@@ -38,4 +38,6 @@ def run(rounds: int = 120, quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -45,4 +45,6 @@ def run(rounds: int = 200, quick: bool = False, lr: float = 5e-3,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -69,8 +69,8 @@ def run(quick: bool = False):
     b, h, hkv, hd, s = 4, 8, 2, 64, (1024 if quick else 4096)
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
     q = jax.random.normal(ks[0], (b, h, hd))
-    kk = jax.random.normal(ks[1], (b, s, hkv, hd))
-    vv = jax.random.normal(ks[2], (b, s, hkv, hd))
+    kk = jax.random.normal(ks[1], (b, hkv, s, hd))
+    vv = jax.random.normal(ks[2], (b, hkv, s, hd))
     pos = jnp.full((b,), s - 1, jnp.int32)
     oracle_attn = jax.jit(lambda *a: ref.decode_attention(*a))
     t_attn = _time(oracle_attn, q, kk, vv, pos)
@@ -85,8 +85,8 @@ def run(quick: bool = False):
     sp = 512 if quick else 1024
     ksp = jax.random.split(jax.random.PRNGKey(4), 3)
     qp = jax.random.normal(ksp[0], (1, sp, 4, 64))
-    kp = jax.random.normal(ksp[1], (1, sp, 2, 64))
-    vp = jax.random.normal(ksp[2], (1, sp, 2, 64))
+    kp = jax.random.normal(ksp[1], (1, 2, sp, 64))
+    vp = jax.random.normal(ksp[2], (1, 2, sp, 64))
     oracle_prefill = jax.jit(lambda *a: ref.prefill_attention(*a))
     t_pref = _time(oracle_prefill, qp, kp, vp, iters=5)
     rows.append([f"prefill_attention_S{sp}", t_pref,
@@ -109,4 +109,6 @@ def run(quick: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -16,7 +16,10 @@ import numpy as np
 from repro.core import aggregation, pruning, tradeoff, wireless
 from repro.core.convergence import ConvergenceBound, SmoothnessParams
 from repro.data import synthetic
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import mlp
+
+enable_compile_cache()
 
 I = 5                                  # UEs (paper Table I)
 SAMPLES = np.array([30, 40, 50, 30, 40], np.float64)

@@ -55,5 +55,9 @@ def restore(path: str, like: PyTree) -> PyTree:
         if arr.shape != leaf.shape:
             raise ValueError(f"shape mismatch for {key!r}: "
                              f"{arr.shape} vs {leaf.shape}")
+        if arr.dtype.kind == "V":
+            # .npy cannot name extension dtypes such as bfloat16 and
+            # stores their raw bytes; reinterpret them as the leaf's dtype
+            arr = arr.view(leaf.dtype)
         leaves.append(arr.astype(leaf.dtype))
     return jax.tree_util.tree_unflatten(treedef, leaves)

@@ -16,7 +16,7 @@ rate curve) — shared by two execution paths:
 Functions take an explicit ``xp`` module; tensors may carry arbitrary
 leading batch dims (cells, grid combos).  The numpy path forces float64
 (matching the original modules); the jax path follows input dtypes so it
-respects an ambient ``enable_x64``.
+respects an ambient ``jax.enable_x64(True)`` context.
 """
 
 from __future__ import annotations

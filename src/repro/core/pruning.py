@@ -211,11 +211,15 @@ def block_thresholds(state: BlockNormState, rate: jnp.ndarray) -> jnp.ndarray:
 
     Tiles whose cumulative element mass is <= rate*total are dropped
     (side="right": an exact tile boundary drops the boundary tile; floor
-    semantics otherwise) — identical to ``block_masks``'s quantile.
+    semantics otherwise) — identical to ``block_masks``'s quantile.  At
+    rate 1 every tile's mass is <= the total, so every tile is dropped and
+    the threshold is +inf.
     """
     rate = jnp.clip(jnp.asarray(rate), 0.0, 1.0)
     idx = jnp.searchsorted(state.cum_frac, rate, side="right")
-    return state.sorted_norms[jnp.clip(idx, 0, state.sorted_norms.size - 1)]
+    t = state.sorted_norms.size
+    return jnp.where(idx >= t, jnp.inf,
+                     state.sorted_norms[jnp.clip(idx, 0, t - 1)])
 
 
 def block_keep(state: list[Optional[BlockNormState]], rates: jnp.ndarray

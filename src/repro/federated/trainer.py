@@ -19,40 +19,16 @@ semantics exactly.
 
 from __future__ import annotations
 
-import inspect
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # JAX >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
 from repro.core import aggregation, pruning
 from repro.fleet.task import FleetTask, TransformerTask
 
 PyTree = Any
-
-_SHARD_MAP_PARAMS = frozenset(inspect.signature(shard_map).parameters)
-
-
-def _hybrid_shard_map(f, mesh: Mesh, in_specs, out_specs,
-                      manual_axes: tuple[str, ...]):
-    """shard_map with ``manual_axes`` Manual and every other mesh axis Auto,
-    across the two API generations: new jax spells this (axis_names=...,
-    check_vma=False), old jax spells it (auto=<complement>, check_rep=False).
-    """
-    if "axis_names" in _SHARD_MAP_PARAMS:
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, axis_names=set(manual_axes),
-                         check_vma=False)
-    auto = frozenset(mesh.axis_names) - set(manual_axes)
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     auto=auto, check_rep=False)
-
 
 def num_clients(mesh: Mesh, client_axes: tuple[str, ...]) -> int:
     n = 1
@@ -109,11 +85,11 @@ def make_task_train_step(task: FleetTask, mesh: Mesh,
     # Auto so the per-client model computation is partitioned across it by
     # GSPMD + the model's logical sharding constraints.  The batch spec is
     # a pytree *prefix*: P(caxes) broadcasts over every batch leaf.
-    mapped = _hybrid_shard_map(
-        step, mesh,
+    mapped = jax.shard_map(
+        step, mesh=mesh,
         in_specs=(P(), P(caxes), P(caxes), P(caxes), P(caxes)),
         out_specs=(P(), {"loss": P(), "achieved_rho": P(caxes)}),
-        manual_axes=client_axes)
+        axis_names=set(client_axes), check_vma=False)
 
     if tp_shard_params and "model" in mesh.axis_names \
             and mesh.shape["model"] > 1:

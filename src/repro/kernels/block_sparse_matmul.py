@@ -14,8 +14,9 @@ accumulator lives in the output block across the sequential sweep
 (contraction = K forward, N transposed).
 
 TPU notes: block sizes default to (128, 128, 128) — MXU-aligned; the
-accumulator is float32 regardless of input dtype.  DMA for masked-off
-blocks is not elided (the BlockSpec still maps them in); a compacted
+accumulator is float32 regardless of input dtype, and the dots run at
+full float32 precision (``HIGHEST``; a bf16 input is one pass anyway).
+DMA for masked-off blocks is not elided (the BlockSpec still maps them in); a compacted
 weight layout that skips the DMA too is recorded as a §Perf follow-up.
 """
 
@@ -28,6 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+_HI = jax.lax.Precision.HIGHEST
 
 def _kernel(mask_ref, x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
     k = pl.program_id(2)
@@ -39,7 +41,7 @@ def _kernel(mask_ref, x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
 
     @pl.when(mask_ref[k, n] != 0)
     def _compute():
-        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
+        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...], precision=_HI,
                                 preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
@@ -60,7 +62,7 @@ def _kernel_t(mask_ref, x_ref, w_ref, o_ref, acc_ref, *, n_n: int):
 
     @pl.when(mask_ref[k, n] != 0)
     def _compute():
-        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...].T,
+        acc_ref[...] += jnp.dot(x_ref[...], w_ref[...].T, precision=_HI,
                                 preferred_element_type=jnp.float32)
 
     @pl.when(n == n_n - 1)
@@ -73,15 +75,17 @@ def _kernel_t(mask_ref, x_ref, w_ref, o_ref, acc_ref, *, n_n: int):
                                     "transpose_rhs", "interpret"))
 def block_sparse_matmul(x: jnp.ndarray, w: jnp.ndarray, mask: jnp.ndarray,
                         block_m: int = 128, block_k: int = 128,
-                        block_n: int = 128, transpose_rhs: bool = False,
-                        interpret: bool = True) -> jnp.ndarray:
+                        block_n: int = 128, transpose_rhs: bool = False, *,
+                        interpret: bool) -> jnp.ndarray:
     """Block-masked matmul; ``mask``: (K//block_k, N//block_n) int32/bool.
 
     Forward (default): x: (M, K), w: (K, N) -> (M, N).
     ``transpose_rhs``:  x: (M, N), w: (K, N) -> (M, K) — the pruned
     layer's backward product, reusing the forward's mask layout.
 
-    All dims must be divisible by their block sizes (ops.py pads).
+    All dims must be divisible by their block sizes (ops.py pads).  On
+    the TPU ``block_m`` must be a multiple of 8 and ``block_k`` /
+    ``block_n`` multiples of 128 (ops.py pads every tile up to that).
     """
     m = x.shape[0]
     kdim, n = w.shape

@@ -4,12 +4,17 @@ Computes attention for a single new token against a length-S KV cache with
 optional sliding window, tiled over KV blocks with an online softmax: the
 running (max, denominator, accumulator) live in VMEM scratch across the
 sequential S-block sweep — the cache streams HBM->VMEM once, the classic
-memory-bound decode pattern.
+memory-bound decode pattern.  Every dot runs at full float32 precision
+(``HIGHEST``): the serving contract is token equality with the dense
+oracle, and a single bf16 pass would re-round each layer's inputs.
 
 Grid: (B, Hkv, S/bs).  Each step handles the G = H/Hkv query heads of one
 KV head so K/V blocks are fetched once per group (GQA's bandwidth win is
-explicit in the tiling).  The per-batch valid length ``pos`` rides in
-scalar prefetch (SMEM) and prunes masked blocks' compute via @pl.when.
+explicit in the tiling).  The KV cache is head-major, (B, Hkv, S, hd),
+so that a block's last two dims are (bs, hd) as the TPU lowering wants;
+the cache is allocated in that layout (serve/model.py), so no call
+relayouts it.  The per-batch valid length ``pos`` rides in scalar
+prefetch (SMEM) and prunes masked blocks' compute via @pl.when.
 
 Mask-aware serving (PR 9): ``head_mask`` marks the *live* KV heads of a
 block-pruned model (a KV head whose wv columns — or whose whole query
@@ -34,6 +39,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
+_HI = jax.lax.Precision.HIGHEST
 
 
 def _kernel(pos_ref, hm_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
@@ -59,9 +65,11 @@ def _kernel(pos_ref, hm_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     @pl.when(jnp.logical_and(live, jnp.logical_and(lo_ok, hi_ok)))
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)                  # (G, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bs, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)            # (bs, hd)
-        scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        k = k_ref[0, 0].astype(jnp.float32)                  # (bs, hd)
+        v = v_ref[0, 0].astype(jnp.float32)                  # (bs, hd)
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), precision=_HI,
+            preferred_element_type=jnp.float32) * scale
         kpos = blk_lo + jax.lax.broadcasted_iota(jnp.int32, (1, block_s), 1)
         valid = kpos <= pos
         if window is not None:
@@ -74,7 +82,7 @@ def _kernel(pos_ref, hm_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
         p = jnp.exp(scores - m_new)                          # (G, bs)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + \
-            jnp.dot(p, v, preferred_element_type=jnp.float32)
+            jnp.dot(p, v, precision=_HI, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(s_idx == n_s - 1)
@@ -88,14 +96,14 @@ def _kernel(pos_ref, hm_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      pos: jnp.ndarray, block_s: int = 512,
                      window: int | None = None,
-                     head_mask: jnp.ndarray | None = None,
-                     interpret: bool = True) -> jnp.ndarray:
-    """q: (B, H, hd); k, v: (B, S, Hkv, hd); pos: (B,) int32.
+                     head_mask: jnp.ndarray | None = None, *,
+                     interpret: bool) -> jnp.ndarray:
+    """q: (B, H, hd); k, v: (B, Hkv, S, hd); pos: (B,) int32.
     ``head_mask``: optional (Hkv,) live-head indicators (>0 = live); dead
     heads are skipped entirely and output zeros.
     Returns (B, H, hd) float32.  S % block_s == 0 (ops.py pads)."""
     b, h, hd = q.shape
-    s, hkv = k.shape[1], k.shape[2]
+    hkv, s = k.shape[1], k.shape[2]
     g = h // hkv
     n_s = s // block_s
     scale = hd ** -0.5
@@ -110,10 +118,10 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             grid=(b, hkv, n_s),
             in_specs=[
                 pl.BlockSpec((1, 1, g, hd), lambda b_, h_, s_, *_: (b_, h_, 0, 0)),
-                pl.BlockSpec((1, block_s, 1, hd),
-                             lambda b_, h_, s_, *_: (b_, s_, h_, 0)),
-                pl.BlockSpec((1, block_s, 1, hd),
-                             lambda b_, h_, s_, *_: (b_, s_, h_, 0)),
+                pl.BlockSpec((1, 1, block_s, hd),
+                             lambda b_, h_, s_, *_: (b_, h_, s_, 0)),
+                pl.BlockSpec((1, 1, block_s, hd),
+                             lambda b_, h_, s_, *_: (b_, h_, s_, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, g, hd),
                                    lambda b_, h_, s_, *_: (b_, h_, 0, 0)),
@@ -145,7 +153,7 @@ def decode_attention_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     padding): the last block is sliced short.
     """
     b, h, hd = q.shape
-    s, hkv = k.shape[1], k.shape[2]
+    hkv, s = k.shape[1], k.shape[2]
     g = h // hkv
     scale = hd ** -0.5
     block_s = min(block_s, s)
@@ -164,8 +172,8 @@ def decode_attention_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         carry = (m0, l0, a0)
         for si in range(n_s):
             lo, hi_ = si * block_s, min(s, (si + 1) * block_s)
-            kb = k[:, lo:hi_, hi].astype(jnp.float32)        # (B, bs, hd)
-            vb = v[:, lo:hi_, hi].astype(jnp.float32)
+            kb = k[:, hi, lo:hi_].astype(jnp.float32)        # (B, bs, hd)
+            vb = v[:, hi, lo:hi_].astype(jnp.float32)
             live = jnp.max(pos) >= lo
             if window is not None:
                 live = jnp.logical_and(live,
@@ -175,7 +183,8 @@ def decode_attention_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
             def upd(carry, kb=kb, vb=vb, lo=lo, hi_=hi_):
                 m, l, acc = carry
-                scores = jnp.einsum("bgd,bsd->bgs", qg[:, hi], kb) * scale
+                scores = jnp.einsum("bgd,bsd->bgs", qg[:, hi], kb,
+                                    precision=_HI) * scale
                 kpos = lo + jnp.arange(hi_ - lo)[None, :]
                 valid = kpos <= pos[:, None]
                 if window is not None:
@@ -186,7 +195,8 @@ def decode_attention_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                 alpha = jnp.exp(m - m_new)
                 p = jnp.exp(scores - m_new)
                 l_new = l * alpha + jnp.sum(p, -1, keepdims=True)
-                a_new = acc * alpha + jnp.einsum("bgs,bsd->bgd", p, vb)
+                a_new = acc * alpha + jnp.einsum("bgs,bsd->bgd", p, vb,
+                                                 precision=_HI)
                 return (m_new, l_new, a_new)
 
             carry = jax.lax.cond(live, upd, lambda c: c, carry)

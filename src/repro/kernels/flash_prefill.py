@@ -10,7 +10,14 @@ which is the roofline floor for attention.
 Grid: (B, Hkv, S/block_q, T/block_s) — the KV sweep is the innermost
 (sequential) axis, so the online-softmax state (m, l, acc) persists in
 VMEM scratch across it (same convention as decode_attention.py).  All
-G = H/Hkv query heads of one KV head share each fetched K/V block.
+G = H/Hkv query heads of one KV head share each fetched K/V block.  K/V
+come head-major, (B, Hkv, T, hd) — the serving KV-cache layout, so the
+keys written to the cache are the keys read here — and the wrapper lays
+q out as (B, Hkv, S*G, hd), row s*G + g, so every block's last two dims
+are (rows, hd) as the TPU lowering wants.  That q relayout (and its
+inverse on the output) is activation-sized and runs once per prefill.
+Every dot runs at full float32 precision (``HIGHEST``), as in
+decode_attention.py.
 
 Causality prunes whole (q, k) block pairs via @pl.when before any MXU
 work; sliding windows prune from the other side.
@@ -33,6 +40,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
+_HI = jax.lax.Precision.HIGHEST
 
 
 def _kernel(hm_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
@@ -63,12 +71,13 @@ def _kernel(hm_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(live)
     def _compute():
-        g, hd = q_ref.shape[3], q_ref.shape[4]
-        q = q_ref[0, :, 0].astype(jnp.float32)               # (bq, G, hd)
-        q = q.reshape(block_q * g, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bs, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)            # (bs, hd)
-        scores = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        g = q_ref.shape[2] // block_q
+        q = q_ref[0, 0].astype(jnp.float32)                  # (bq*G, hd)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (bs, hd)
+        v = v_ref[0, 0].astype(jnp.float32)                  # (bs, hd)
+        scores = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), precision=_HI,
+            preferred_element_type=jnp.float32) * scale
 
         rows = jax.lax.broadcasted_iota(jnp.int32, (block_q * g, block_s), 0)
         qpos = q_lo + rows // g
@@ -87,14 +96,13 @@ def _kernel(hm_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         p = jnp.exp(scores - m_new)
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + \
-            jnp.dot(p, v, preferred_element_type=jnp.float32)
+            jnp.dot(p, v, precision=_HI, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
     @pl.when(ki == n_k - 1)
     def _flush():
-        g, hd = q_ref.shape[3], q_ref.shape[4]
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0] = out.reshape(block_q, g, hd).astype(o_ref.dtype)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -103,19 +111,20 @@ def flash_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                   block_q: int = 256, block_s: int = 512,
                   causal: bool = True, window: int | None = None,
                   t_valid: int | None = None,
-                  head_mask: jnp.ndarray | None = None,
-                  interpret: bool = True) -> jnp.ndarray:
-    """q: (B, S, H, hd); k, v: (B, T, Hkv, hd).  Returns (B, S, H, hd)
+                  head_mask: jnp.ndarray | None = None, *,
+                  interpret: bool) -> jnp.ndarray:
+    """q: (B, S, H, hd); k, v: (B, Hkv, T, hd).  Returns (B, S, H, hd)
     float32.  S % block_q == 0 and T % block_s == 0 (ops.py pads);
     ``t_valid`` masks padded keys (defaults to T).  ``head_mask``:
     optional (Hkv,) live-head indicators; dead heads output zeros."""
     b, s, h, hd = q.shape
-    t, hkv = k.shape[1], k.shape[2]
+    hkv, t = k.shape[1], k.shape[2]
     g = h // hkv
     n_q, n_k = s // block_q, t // block_s
     t_valid = t if t_valid is None else t_valid
     scale = hd ** -0.5
-    qg = q.reshape(b, s, hkv, g, hd)
+    qg = q.reshape(b, s, hkv, g, hd).transpose(0, 2, 1, 3, 4) \
+        .reshape(b, hkv, s * g, hd)
     hm = jnp.ones((hkv,), jnp.int32) if head_mask is None \
         else (jnp.asarray(head_mask) > 0).astype(jnp.int32)
     out = pl.pallas_call(
@@ -126,25 +135,26 @@ def flash_prefill(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
             num_scalar_prefetch=1,
             grid=(b, hkv, n_q, n_k),
             in_specs=[
-                pl.BlockSpec((1, block_q, 1, g, hd),
-                             lambda b_, h_, q_, k_, *_: (b_, q_, h_, 0, 0)),
-                pl.BlockSpec((1, block_s, 1, hd),
-                             lambda b_, h_, q_, k_, *_: (b_, k_, h_, 0)),
-                pl.BlockSpec((1, block_s, 1, hd),
-                             lambda b_, h_, q_, k_, *_: (b_, k_, h_, 0)),
+                pl.BlockSpec((1, 1, block_q * g, hd),
+                             lambda b_, h_, q_, k_, *_: (b_, h_, q_, 0)),
+                pl.BlockSpec((1, 1, block_s, hd),
+                             lambda b_, h_, q_, k_, *_: (b_, h_, k_, 0)),
+                pl.BlockSpec((1, 1, block_s, hd),
+                             lambda b_, h_, q_, k_, *_: (b_, h_, k_, 0)),
             ],
-            out_specs=pl.BlockSpec((1, block_q, 1, g, hd),
-                                   lambda b_, h_, q_, k_, *_: (b_, q_, h_, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, block_q * g, hd),
+                                   lambda b_, h_, q_, k_, *_: (b_, h_, q_, 0)),
             scratch_shapes=[
                 pltpu.VMEM((block_q * g, 1), jnp.float32),
                 pltpu.VMEM((block_q * g, 1), jnp.float32),
                 pltpu.VMEM((block_q * g, hd), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, s, hkv, g, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, s * g, hd), jnp.float32),
         interpret=interpret,
     )(hm, qg, k, v)
-    return out.reshape(b, s, h, hd)
+    return out.reshape(b, hkv, s, g, hd).transpose(0, 2, 1, 3, 4) \
+        .reshape(b, s, h, hd)
 
 
 def flash_prefill_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -157,9 +167,10 @@ def flash_prefill_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     are *static* — dead block pairs and statically dead KV heads never
     enter the trace, so prefill compute scales with the live fraction.
     A traced ``head_mask`` degrades to a per-head ``lax.cond``.  Ragged S
-    and T are sliced short (no padding needed)."""
+    and T are sliced short (no padding needed).  Layouts as in
+    ``flash_prefill``."""
     b, s, h, hd = q.shape
-    t, hkv = k.shape[1], k.shape[2]
+    hkv, t = k.shape[1], k.shape[2]
     g = h // hkv
     scale = hd ** -0.5
     block_q = min(block_q, s)
@@ -191,13 +202,14 @@ def flash_prefill_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     live = live and (k_hi - 1 > q_lo - window)
                 if not live:
                     continue
-                kb = k[:, k_lo:k_hi, hi].astype(jnp.float32)
-                vb = v[:, k_lo:k_hi, hi].astype(jnp.float32)
+                kb = k[:, hi, k_lo:k_hi].astype(jnp.float32)
+                vb = v[:, hi, k_lo:k_hi].astype(jnp.float32)
 
                 def upd(carry, kb=kb, vb=vb, k_lo=k_lo, k_hi=k_hi,
                         q_lo=q_lo, q_hi=q_hi, qb=qb):
                     m, l, acc = carry
-                    scores = jnp.einsum("bqgd,bsd->bqgs", qb, kb) * scale
+                    scores = jnp.einsum("bqgd,bsd->bqgs", qb, kb,
+                                        precision=_HI) * scale
                     qpos = q_lo + jnp.arange(q_hi - q_lo)[:, None]
                     kpos = k_lo + jnp.arange(k_hi - k_lo)[None, :]
                     valid = kpos < t_valid
@@ -211,7 +223,7 @@ def flash_prefill_xla(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     p = jnp.exp(scores - m_new)
                     l_new = l * alpha + jnp.sum(p, -1, keepdims=True)
                     a_new = acc * alpha + \
-                        jnp.einsum("bqgs,bsd->bqgd", p, vb)
+                        jnp.einsum("bqgs,bsd->bqgd", p, vb, precision=_HI)
                     return (m_new, l_new, a_new)
 
                 if static_hm:

@@ -33,7 +33,10 @@ Three implementations of identical math (equivalence-tested):
   ``pruning.block_masks``; the oracle the other two are tested against.
 
 ``fused_fleet_grads`` dispatches: Pallas when the backend is TPU,
-XLA otherwise.
+XLA otherwise.  The fused paths' dots run at full float32 precision
+(``HIGHEST``), so on the TPU they match the oracle to float32 rounding
+rather than to one bf16 pass; at the MLP's widths the extra MXU passes
+are not what bounds the kernel.
 
 The three kernels above are layer-structured (the MLP's ``layer{i}``
 layout).  ``masked_scan_grads`` is the *model-agnostic* sibling used by
@@ -58,6 +61,7 @@ from repro.core import pruning
 PyTree = Any
 
 DEFAULT_TILE_CLIENTS = 8
+_HI = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -154,7 +158,7 @@ def fused_grads_xla(params: dict, x: jnp.ndarray, y: jnp.ndarray,
                               total_repeat_length=kdim)       # (c, K_l)
             kexps.append(kexp)
             xs = (acts3[-1] * kexp[:, None, :]).reshape(rows, kdim)
-            cols.append(xs @ ws[l][:, n0:n1])
+            cols.append(jnp.dot(xs, ws[l][:, n0:n1], precision=_HI))
         z = jnp.concatenate(cols, axis=-1) + bs[l]
         zs.append(z)
         a_next = jax.nn.relu(z) if l < nl - 1 else z
@@ -184,14 +188,15 @@ def fused_grads_xla(params: dict, x: jnp.ndarray, y: jnp.ndarray,
             kexpn = jnp.repeat(keeps[l][:, ti, :], nsizes, axis=1,
                                total_repeat_length=ndim)      # (c, N_l)
             dzm = (dzw3 * kexpn[:, None, :]).reshape(rows, ndim)
-            dw_rows.append(a2[:, k0:k1].T @ dzm)
+            dw_rows.append(jnp.dot(a2[:, k0:k1].T, dzm, precision=_HI))
         dw = jnp.concatenate(dw_rows, axis=0)
         db = jnp.sum(dzw3.reshape(rows, ndim), axis=0)
         layer_grads[l] = (dw, db)
         if l > 0:
             da3 = None
             for uj, (n0, n1) in enumerate(nt):
-                part = (dz[:, n0:n1] @ ws[l][:, n0:n1].T) \
+                part = jnp.dot(dz[:, n0:n1], ws[l][:, n0:n1].T,
+                               precision=_HI) \
                     .reshape(c, batch, kdim) * kexp_cache[l][uj][:, None, :]
                 da3 = part if da3 is None else da3 + part
             dz = da3.reshape(rows, kdim) * (zs[l - 1] > 0)
@@ -212,11 +217,16 @@ def _pad_axis(a: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
 
 
 def _build_fused_kernel(nl: int, dims: list[tuple[int, int]], block: int,
-                        tile_c: int, batch: int, n_classes: int):
+                        n_classes: int):
     """Close over the static layer layout and return the kernel body.
 
-    Ref order: x, y, wts, keep_0..keep_{L-1}, w_0, b_0, .., w_{L-1},
-    b_{L-1} | losses, dw_0, db_0, .., dw_{L-1}, db_{L-1} | per-layer
+    Every per-client quantity arrives already expanded to one row per
+    sample (the wrapper repeats keeps and weights over the batch), so the
+    body is 2-D throughout: Mosaic has no layout for the (clients, batch)
+    <-> rows reshapes or for 1-D vectors.
+
+    Ref order: x, y, row_wts, keep_0..keep_{L-1}, w_0, b_0, .., w_{L-1},
+    b_{L-1} | row_nll, dw_0, db_0, .., dw_{L-1}, db_{L-1} | per-layer
     (acc_dw, acc_db) VMEM scratch.
     """
     n_tiles = [(len(_tile_slices(k, block)), len(_tile_slices(n, block)))
@@ -228,7 +238,7 @@ def _build_fused_kernel(nl: int, dims: list[tuple[int, int]], block: int,
         w_refs = [refs[3 + nl + 2 * l] for l in range(nl)]
         b_refs = [refs[3 + nl + 2 * l + 1] for l in range(nl)]
         out0 = 3 + 3 * nl
-        loss_ref = refs[out0]
+        nll_ref = refs[out0]
         dw_refs = [refs[out0 + 1 + 2 * l] for l in range(nl)]
         db_refs = [refs[out0 + 2 + 2 * l] for l in range(nl)]
         acc0 = out0 + 1 + 2 * nl
@@ -243,30 +253,30 @@ def _build_fused_kernel(nl: int, dims: list[tuple[int, int]], block: int,
                 acc_dw[l][...] = jnp.zeros_like(acc_dw[l])
                 acc_db[l][...] = jnp.zeros_like(acc_db[l])
 
+        def keep_col(l, ti, uj):                     # (rows, 1)
+            t = ti * n_tiles[l][1] + uj
+            return keep_refs[l][:, t:t + 1]
+
         # -- forward: per-tile dots, predicated on any client keeping it
         a = x_ref[...].astype(jnp.float32)
-        keep_rows = [jnp.repeat(keep_refs[l][...], batch, axis=0)
-                     for l in range(nl)]
         acts, zs = [a], []
         for l in range(nl):
             kt = _tile_slices(dims[l][0], block)
             nt = _tile_slices(dims[l][1], block)
-            tn = n_tiles[l][1]
             cols = []
             for uj, (n0, n1) in enumerate(nt):
                 acc = jnp.zeros((a.shape[0], n1 - n0), jnp.float32)
                 for ti, (k0, k1) in enumerate(kt):
-                    kvec = keep_rows[l][:, ti * tn + uj]
+                    kvec = keep_col(l, ti, uj)
                     acc = acc + jax.lax.cond(
                         jnp.max(kvec) > 0,
                         lambda a_=acts[l], kv=kvec, k0=k0, k1=k1,
                         n0=n0, n1=n1, wr=w_refs[l]: jnp.dot(
-                            a_[:, k0:k1], wr[k0:k1, n0:n1],
-                            preferred_element_type=jnp.float32)
-                        * kv[:, None],
+                            a_[:, k0:k1], wr[k0:k1, n0:n1], precision=_HI,
+                            preferred_element_type=jnp.float32) * kv,
                         lambda s=acc.shape: jnp.zeros(s, jnp.float32))
                 cols.append(acc)
-            z = jnp.concatenate(cols, axis=-1) + b_refs[l][0, :]
+            z = jnp.concatenate(cols, axis=-1) + b_refs[l][...]
             zs.append(z)
             acts.append(jax.nn.relu(z) if l < nl - 1 else z)
 
@@ -275,46 +285,43 @@ def _build_fused_kernel(nl: int, dims: list[tuple[int, int]], block: int,
         col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
         logits = jnp.where(col < n_classes, logits, -1e30)
         logp = jax.nn.log_softmax(logits, axis=-1)
-        yv = y_ref[...][:, 0]
-        onehot = (yv[:, None] == col).astype(jnp.float32)
-        nll = -jnp.sum(logp * onehot, axis=-1)
-        loss_ref[...] = jnp.mean(nll.reshape(tile_c, batch), axis=-1,
-                                 keepdims=True)
-        dz = (jnp.exp(logp) - onehot) / batch
+        onehot = (y_ref[...] == col).astype(jnp.float32)
+        nll_ref[...] = -jnp.sum(logp * onehot, axis=-1, keepdims=True)
+        dz = jnp.exp(logp) - onehot
 
-        # -- backward sweep, accumulating into VMEM scratch
-        wv = wts_ref[...][:, 0]
-        w_rows = jnp.repeat(wv, batch)
+        # -- backward sweep, accumulating into VMEM scratch.  Row weights
+        # carry the client's aggregation weight over its batch size.
+        w_rows = wts_ref[...]                            # (rows, 1)
         for l in reversed(range(nl)):
             kt = _tile_slices(dims[l][0], block)
             nt = _tile_slices(dims[l][1], block)
-            tn = n_tiles[l][1]
             for ti, (k0, k1) in enumerate(kt):
                 for uj, (n0, n1) in enumerate(nt):
-                    svec = keep_rows[l][:, ti * tn + uj] * w_rows
+                    svec = keep_col(l, ti, uj) * w_rows
                     contrib = jax.lax.cond(
                         jnp.max(svec) > 0,
                         lambda a_=acts[l], sv=svec, d=dz, k0=k0, k1=k1,
-                        n0=n0, n1=n1: jnp.dot(
-                            (a_[:, k0:k1] * sv[:, None]).T, d[:, n0:n1],
+                        n0=n0, n1=n1: jax.lax.dot_general(
+                            a_[:, k0:k1] * sv, d[:, n0:n1],
+                            (((0,), (0,)), ((), ())), precision=_HI,
                             preferred_element_type=jnp.float32),
                         lambda s=(k1 - k0, n1 - n0): jnp.zeros(
                             s, jnp.float32))
                     acc_dw[l][k0:k1, n0:n1] += contrib
-            acc_db[l][0, :] += jnp.sum(dz * w_rows[:, None], axis=0)
+            acc_db[l][...] += jnp.sum(dz * w_rows, axis=0, keepdims=True)
             if l > 0:
                 cols = []
                 for ti, (k0, k1) in enumerate(kt):
                     acc = jnp.zeros((dz.shape[0], k1 - k0), jnp.float32)
                     for uj, (n0, n1) in enumerate(nt):
-                        kvec = keep_rows[l][:, ti * tn + uj]
+                        kvec = keep_col(l, ti, uj)
                         acc = acc + jax.lax.cond(
                             jnp.max(kvec) > 0,
                             lambda d=dz, kv=kvec, k0=k0, k1=k1, n0=n0,
-                            n1=n1, wr=w_refs[l]: jnp.dot(
-                                d[:, n0:n1], wr[k0:k1, n0:n1].T,
-                                preferred_element_type=jnp.float32)
-                            * kv[:, None],
+                            n1=n1, wr=w_refs[l]: jax.lax.dot_general(
+                                d[:, n0:n1], wr[k0:k1, n0:n1],
+                                (((1,), (1,)), ((), ())), precision=_HI,
+                                preferred_element_type=jnp.float32) * kv,
                             lambda s=acc.shape: jnp.zeros(s, jnp.float32))
                     cols.append(acc)
                 dz = jnp.concatenate(cols, axis=-1) * (zs[l - 1] > 0)
@@ -330,9 +337,9 @@ def _build_fused_kernel(nl: int, dims: list[tuple[int, int]], block: int,
 
 def fused_grads_pallas(params: dict, x: jnp.ndarray, y: jnp.ndarray,
                        keeps: Sequence[jnp.ndarray], weights: jnp.ndarray,
-                       block: int,
-                       tile_clients: int = DEFAULT_TILE_CLIENTS,
-                       interpret: bool = True) -> tuple[dict, jnp.ndarray]:
+                       block: int, *, interpret: bool,
+                       tile_clients: int = DEFAULT_TILE_CLIENTS
+                       ) -> tuple[dict, jnp.ndarray]:
     """Pallas streaming version of ``fused_grads_xla`` (same signature and
     semantics).  Clients are swept ``tile_clients`` at a time; gradient
     accumulators live in VMEM scratch across the sweep and the
@@ -351,30 +358,32 @@ def fused_grads_pallas(params: dict, x: jnp.ndarray, y: jnp.ndarray,
            for b in bs]
     dims = [tuple(w.shape) for w in wsp]
 
+    def rows(a):                    # (clients, ...) -> one row per sample
+        a = _pad_axis(a.reshape(c, -1), 0, tile_clients)
+        return jnp.repeat(a, batch, axis=0).astype(jnp.float32)
+
     xf = _pad_axis(_pad_axis(x.reshape(c * batch, d), 0, tile_r), 1, block)
     yf = _pad_axis(y.reshape(c * batch, 1).astype(jnp.int32), 0, tile_r)
-    wts = _pad_axis(weights.reshape(c, 1), 0, tile_clients)
-    keeps2 = [_pad_axis(k.reshape(c, -1), 0, tile_clients).astype(jnp.float32)
-              for k in keeps]
+    wts = rows(weights) / batch
+    keeps2 = [rows(k) for k in keeps]
 
     grid = (cp // tile_clients,)
-    kernel = _build_fused_kernel(nl, dims, block, tile_clients, batch,
-                                 bs[-1].shape[0])
+    kernel = _build_fused_kernel(nl, dims, block, bs[-1].shape[0])
 
     in_specs = [
         pl.BlockSpec((tile_r, xf.shape[1]), lambda i: (i, 0)),
         pl.BlockSpec((tile_r, 1), lambda i: (i, 0)),
-        pl.BlockSpec((tile_clients, 1), lambda i: (i, 0)),
+        pl.BlockSpec((tile_r, 1), lambda i: (i, 0)),
     ]
     for k in keeps2:
-        in_specs.append(pl.BlockSpec((tile_clients, k.shape[1]),
+        in_specs.append(pl.BlockSpec((tile_r, k.shape[1]),
                                      lambda i: (i, 0)))
     for w, b in zip(wsp, bsp):
         in_specs.append(pl.BlockSpec(w.shape, lambda i: (0, 0)))
         in_specs.append(pl.BlockSpec(b.shape, lambda i: (0, 0)))
 
-    out_shapes = [jax.ShapeDtypeStruct((cp, 1), jnp.float32)]
-    out_specs = [pl.BlockSpec((tile_clients, 1), lambda i: (i, 0))]
+    out_shapes = [jax.ShapeDtypeStruct((cp * batch, 1), jnp.float32)]
+    out_specs = [pl.BlockSpec((tile_r, 1), lambda i: (i, 0))]
     scratch = []
     for w, b in zip(wsp, bsp):
         out_shapes.append(jax.ShapeDtypeStruct(w.shape, jnp.float32))
@@ -392,11 +401,11 @@ def fused_grads_pallas(params: dict, x: jnp.ndarray, y: jnp.ndarray,
         out_shape=out_shapes,
         scratch_shapes=scratch,
         interpret=interpret,
-    )(xf.astype(jnp.float32), yf, wts.astype(jnp.float32),
-      *keeps2, *[a for pair in zip(
+    )(xf.astype(jnp.float32), yf, wts, *keeps2,
+      *[a for pair in zip(
           (w.astype(jnp.float32) for w in wsp), bsp) for a in pair])
 
-    losses = outs[0][:c, 0]
+    losses = outs[0][:c * batch, 0].reshape(c, batch).mean(axis=-1)
     layer_grads = []
     for l in range(nl):
         dw = outs[1 + 2 * l][:ws[l].shape[0], :ws[l].shape[1]]

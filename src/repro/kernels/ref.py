@@ -44,18 +44,18 @@ def prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                       head_mask: jnp.ndarray | None = None) -> jnp.ndarray:
     """Full-sequence GQA attention oracle.
 
-    q: (B, S, H, hd); k, v: (B, T, Hkv, hd).  Query i sits at absolute
+    q: (B, S, H, hd); k, v: (B, Hkv, T, hd).  Query i sits at absolute
     position i; keys at 0..T-1.  ``head_mask`` (Hkv,) zeros the output of
     dead KV heads (the lossless block-pruned-serving skip — see
     decode_attention.py).  Returns (B, S, H, hd) float32.
     """
     b, s, h, hd = q.shape
-    t, hkv = k.shape[1], k.shape[2]
+    hkv, t = k.shape[1], k.shape[2]
     g = h // hkv
     t_valid = t if t_valid is None else t_valid
     scale = hd ** -0.5 if scale is None else scale
     qg = q.reshape(b, s, hkv, g, hd).astype(jnp.float32)
-    scores = jnp.einsum("bskgd,btkd->bskgt", qg,
+    scores = jnp.einsum("bskgd,bktd->bskgt", qg,
                         k.astype(jnp.float32)) * scale
     qpos = jnp.arange(s)[:, None]
     kpos = jnp.arange(t)[None, :]
@@ -66,7 +66,7 @@ def prefill_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         valid = valid & (kpos > qpos - window)
     scores = jnp.where(valid[None, :, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bskgt,btkd->bskgd", probs, v.astype(jnp.float32))
+    out = jnp.einsum("bskgt,bktd->bskgd", probs, v.astype(jnp.float32))
     if head_mask is not None:
         live = (jnp.asarray(head_mask) > 0).astype(jnp.float32)
         out = out * live[None, None, :, None, None]
@@ -79,17 +79,17 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      head_mask: jnp.ndarray | None = None) -> jnp.ndarray:
     """One-token GQA decode.
 
-    q: (B, H, hd); k, v: (B, S, Hkv, hd); pos: (B,) absolute position of
+    q: (B, H, hd); k, v: (B, Hkv, S, hd); pos: (B,) absolute position of
     the query token (keys at indices <= pos are valid, and > pos - window
     if windowed).  ``head_mask`` (Hkv,) zeros the output of dead KV heads.
     Returns (B, H, hd) float32.
     """
     b, h, hd = q.shape
-    s, hkv = k.shape[1], k.shape[2]
+    hkv, s = k.shape[1], k.shape[2]
     g = h // hkv
     scale = hd ** -0.5 if scale is None else scale
     qg = q.reshape(b, hkv, g, hd).astype(jnp.float32)
-    scores = jnp.einsum("bkgd,bskd->bkgs", qg,
+    scores = jnp.einsum("bkgd,bksd->bkgs", qg,
                         k.astype(jnp.float32)) * scale
     kpos = jnp.arange(s)[None, :]
     valid = kpos <= pos[:, None]
@@ -97,7 +97,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         valid &= kpos > (pos[:, None] - window)
     scores = jnp.where(valid[:, None, None, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bkgs,bskd->bkgd", probs, v.astype(jnp.float32))
+    out = jnp.einsum("bkgs,bksd->bkgd", probs, v.astype(jnp.float32))
     if head_mask is not None:
         live = (jnp.asarray(head_mask) > 0).astype(jnp.float32)
         out = out * live[None, :, None, None]

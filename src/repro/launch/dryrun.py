@@ -32,6 +32,11 @@ from repro.launch import steps as ST
 from repro.models import sharding as MS
 
 
+# The chip the production meshes plan for, as a described v5e topology
+# reports its ``device_kind``; roofline terms use its peaks.
+PLANNED_DEVICE_KIND = "TPU v5 lite"
+
+
 def mesh_tag(multi_pod: bool) -> str:
     return "2x16x16" if multi_pod else "16x16"
 
@@ -68,7 +73,7 @@ def dryrun_one(arch: str, shape_name: str, multi_pod: bool = False,
 
     wall = time.time() - t0
     mem = compiled.memory_analysis()
-    cost = HC.xla_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     # loop-aware counters: XLA's cost_analysis counts while bodies ONCE;
     # hlo_cost re-derives flops/bytes/collective bytes with trip counts
     hc = HC.hlo_cost(compiled.as_text(),
@@ -93,6 +98,7 @@ def dryrun_one(arch: str, shape_name: str, multi_pod: bool = False,
                      for op in hc.collective_counts},
         model_flops=RF.model_flops(cfg, shape, n_active),
         wall_s=wall,
+        device_kind=PLANNED_DEVICE_KIND,
         raw_xla_flops=float(cost.get("flops", 0.0)),
         raw_xla_bytes=float(cost.get("bytes accessed", 0.0)),
     )
@@ -153,10 +159,6 @@ def fleet_dryrun(verbose: bool = True) -> dict:
     import numpy as np
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # jax >= 0.6 promotes it out of experimental
-        from jax import shard_map
     from repro.core import wireless as W
     from repro.fleet import solver as FSOLVER
 
@@ -186,10 +188,10 @@ def fleet_dryrun(verbose: bool = True) -> dict:
 
     cell_spec = P("cells")
     t0 = time.time()
-    solve_sharded = jax.jit(shard_map(
+    solve_sharded = jax.jit(jax.shard_map(
         solve_block, mesh=mesh,
         in_specs=(cell_spec,) * 7, out_specs=cell_spec,
-        check_rep=False))
+        check_vma=False))
     sol = solve_sharded(h_up, k, cpu, p_tx, rho_max, m_cell, mask)
     jax.block_until_ready(sol.prune)
     solve_s = time.time() - t0
@@ -213,9 +215,9 @@ def fleet_dryrun(verbose: bool = True) -> dict:
         return jax.lax.psum(jnp.einsum("c,c...->...", w_i, g_i), "data")
 
     t0 = time.time()
-    grad_sharded = jax.jit(shard_map(
+    grad_sharded = jax.jit(jax.shard_map(
         grad_block, mesh=mesh, in_specs=(P("data"), P("data")),
-        out_specs=P(), check_rep=False))
+        out_specs=P(), check_vma=False))
     g_sum = grad_sharded(wts, grads)
     jax.block_until_ready(g_sum)
     grad_s = time.time() - t0

@@ -190,13 +190,6 @@ def _trip_count(instr: Instr, comps: dict) -> int:
     return 1
 
 
-def xla_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax generations: < 0.5 returns a
-    one-element list of dicts, newer returns the dict directly."""
-    cost = compiled.cost_analysis()
-    return cost[0] if isinstance(cost, list) else cost
-
-
 @dataclasses.dataclass
 class Cost:
     flops: float = 0.0

@@ -13,17 +13,9 @@ MULTI_POD = (2, 16, 16)                   # 2 pods = 512 chips
 
 
 def make_mesh(shape, axes):
-    """``jax.make_mesh`` with Auto axis types where the API supports them.
-
-    jax < 0.5 has neither ``jax.sharding.AxisType`` nor the ``axis_types``
-    kwarg; Auto is its only (implicit) behaviour, so plain ``make_mesh`` is
-    equivalent there.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis of type Auto."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

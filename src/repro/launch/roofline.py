@@ -1,10 +1,11 @@
 """Roofline-term extraction from compiled dry-run artifacts.
 
-Three terms per (arch x shape x mesh), in seconds (TPU v5e targets):
+Three terms per (arch x shape x mesh), in seconds, against the peaks of
+the report's ``device_kind`` (``PEAKS``):
 
-  compute    = HLO_FLOPs_per_chip / peak_FLOP/s           (197 TF bf16)
-  memory     = HLO_bytes_per_chip / HBM_bw                 (819 GB/s)
-  collective = collective_operand_bytes_per_chip / link_bw (~50 GB/s/link)
+  compute    = HLO_FLOPs_per_chip / peak_FLOP/s
+  memory     = HLO_bytes_per_chip / HBM_bw
+  collective = collective_operand_bytes_per_chip / link_bw
 
 ``compiled.cost_analysis()`` is evaluated on the post-SPMD per-device
 module, so its flops / bytes-accessed numbers are already per chip.
@@ -21,10 +22,33 @@ import json
 import re
 from typing import Optional
 
-# --- TPU v5e hardware constants (per chip) ---------------------------------
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s per link
+
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+    flops: float             # bf16 FLOP/s
+    hbm_bw: float            # HBM bytes/s
+    hbm_bytes: float         # HBM capacity
+    ici_bw: float            # interconnect bytes/s per link
+
+
+# Keyed by ``jax.Device.device_kind``.  TPU v5e: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB of HBM at 819 GB/s,
+# 1,600 Gbit/s of interconnect over 4 links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, hbm_bytes=16e9,
+                             ici_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; a kind not in ``PEAKS`` is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}"
+                       f" (known: {sorted(PEAKS)})") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -99,6 +123,7 @@ class RooflineReport:
     collectives: dict             # opcode -> {count, bytes}
     model_flops: float            # 6ND (train) / 2ND (prefill/decode), global
     wall_s: float                 # lower+compile wall time
+    device_kind: str              # key into PEAKS
     raw_xla_flops: float = 0.0    # cost_analysis() (loop bodies counted once)
     raw_xla_bytes: float = 0.0
 
@@ -106,15 +131,16 @@ class RooflineReport:
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_chip / PEAK_FLOPS
+        return self.flops_per_chip / chip_peaks(self.device_kind).flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_chip / HBM_BW
+        return self.bytes_per_chip / chip_peaks(self.device_kind).hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes_per_chip / ICI_BW
+        return (self.collective_bytes_per_chip
+                / chip_peaks(self.device_kind).ici_bw)
 
     @property
     def bottleneck(self) -> str:

@@ -101,14 +101,10 @@ def constrain(x, *logical_axes):
     # may only name the remaining Auto axes (hybrid shard_map).  Fully
     # manual context -> no-op.
     manual: set = set()
-    # jax < 0.5 has no abstract-mesh tracking; there the hybrid-manual
-    # detection degrades to the installed-rules mesh.
-    _get_ctx = getattr(jax.sharding, "get_abstract_mesh", None)
-    ctx = _get_ctx() if _get_ctx is not None else None
-    if ctx is not None and not ctx.empty:
-        manual = {name for name, t in zip(ctx.axis_names,
-                                          getattr(ctx, "axis_types", ()))
-                  if "Manual" in str(t)}
+    ctx = jax.sharding.get_abstract_mesh()
+    if not ctx.empty:
+        manual = {name for name, t in zip(ctx.axis_names, ctx.axis_types)
+                  if t == jax.sharding.AxisType.Manual}
         if manual:
             if len(manual) == len(ctx.axis_names):
                 return x

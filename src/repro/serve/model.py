@@ -23,7 +23,10 @@ pruned-column values nonzero.)
 
 Scope: llama-family decoders (pre-norm attn+MLP blocks, global causal
 GQA, no MoE/MLA/recurrent mixers, no encoder/memory) — which covers the
-fleet tasks' smoke variants.  Everything computes in float32.
+fleet tasks' smoke variants.  Everything computes in float32, every
+matmul at full float32 precision (see serve/sparse.py for why).  KV
+caches are head-major, (B, Hkv, S, hd), the layout the attention kernels
+read, so a decode step never relayouts the cache.
 """
 
 from __future__ import annotations
@@ -196,7 +199,7 @@ class SparseModel:
     # -- caches -----------------------------------------------------------
 
     def init_caches(self, batch: int, cache_len: int) -> list[dict]:
-        shape = (batch, cache_len, self.aspec.num_kv_heads,
+        shape = (batch, self.aspec.num_kv_heads, cache_len,
                  self.aspec.head_dim)
         return [{"k": jnp.zeros(shape, jnp.float32),
                  "v": jnp.zeros(shape, jnp.float32)}
@@ -241,12 +244,12 @@ class SparseModel:
         for plan, la, cache in zip(self.layers, arrays["layers"], caches):
             y = B.norm_apply(cfg, la["norm_mix"], x)
             q, k, v = self._qkv(plan, la, y, pos[:, None])
-            cache_len = cache["k"].shape[1]
+            cache_len = cache["k"].shape[2]
             slot = jnp.minimum(pos, cache_len - 1)
-            onehot = (jnp.arange(cache_len)[None, :, None, None]
+            onehot = (jnp.arange(cache_len)[None, None, :, None]
                       == slot[:, None, None, None])
-            new_k = jnp.where(onehot, k, cache["k"])
-            new_v = jnp.where(onehot, v, cache["v"])
+            new_k = jnp.where(onehot, k.transpose(0, 2, 1, 3), cache["k"])
+            new_v = jnp.where(onehot, v.transpose(0, 2, 1, 3), cache["v"])
             attn = ops.flash_decode(q[:, 0], new_k, new_v, pos,
                                     head_mask=plan["head_mask"],
                                     impl=self.attn_impl,
@@ -276,6 +279,7 @@ class SparseModel:
         for plan, la in zip(self.layers, arrays["layers"]):
             y = B.norm_apply(cfg, la["norm_mix"], x)
             q, k, v = self._qkv(plan, la, y, positions)
+            k, v = k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3)
             attn = ops.flash_prefill(q, k, v, causal=True,
                                      head_mask=plan["head_mask"],
                                      impl=self.attn_impl,
@@ -285,9 +289,9 @@ class SparseModel:
             x = x + h
             if plan["has_ffn"]:
                 x = self._ffn(plan, la, x)
-            shape = (b, cache_len, sp.num_kv_heads, sp.head_dim)
-            ck = jnp.zeros(shape, jnp.float32).at[:, :p].set(k)
-            cv = jnp.zeros(shape, jnp.float32).at[:, :p].set(v)
+            shape = (b, sp.num_kv_heads, cache_len, sp.head_dim)
+            ck = jnp.zeros(shape, jnp.float32).at[:, :, :p].set(k)
+            cv = jnp.zeros(shape, jnp.float32).at[:, :, :p].set(v)
             caches.append({"k": ck, "v": cv})
         x = B.norm_apply(cfg, arrays["final_norm"], x)
         logits = sparse.apply_linear(self.unembed, arrays["unembed"], x)

@@ -21,10 +21,16 @@ pytree (passed through jit, so weights aren't baked into the executable):
   impl="cond"     per-tile ``lax.cond`` skip, the direct analogue of
                   fleet_fused's training-side tile loop.  Trace size is
                   O(Tk*Tn) per layer: debug/small-model use.
-  impl="pallas"   ``ops.masked_matmul`` (the Pallas kernel; interpreted
-                  off-TPU).
+  impl="pallas"   ``ops.masked_matmul_padded`` (the Pallas kernel;
+                  interpreted off-TPU) on a weight padded to the hardware
+                  tiling once, at build.
   impl="dense"    masked dense matmul — the oracle and the speedup
                   baseline.
+
+Every impl multiplies at full float32 precision (``HIGHEST``).  The TPU's
+default is one bf16 pass, which re-rounds each layer's input: two impls
+that sum in different orders can then drift apart layer by layer until
+a greedy argmax flips, breaking the dense-masked equality.
 """
 
 from __future__ import annotations
@@ -38,6 +44,7 @@ import numpy as np
 from repro.kernels import ops
 
 IMPLS = ("gather", "cond", "pallas", "dense")
+_HI = jax.lax.Precision.HIGHEST
 
 
 def _masked(w: jnp.ndarray, keep: np.ndarray, bk: int, bn: int) -> jnp.ndarray:
@@ -86,7 +93,7 @@ def make_linear(w: jnp.ndarray, keep, blocks: tuple[int, int],
         arrays["w"] = jnp.pad(wm, ((0, tk * bk - k), (0, tn * bn - n)))
         arrays["keep"] = jnp.asarray(keep_np > 0)
     elif impl == "pallas":
-        arrays["w"] = wm
+        arrays["w"] = ops.pad_weight_tiles(wm, bk, bn)
         arrays["keep"] = jnp.asarray(keep_np, jnp.float32)
     else:                                           # dense
         arrays["w"] = wm
@@ -102,7 +109,8 @@ def _apply_gather(plan: dict, arrays: dict, x2: jnp.ndarray) -> jnp.ndarray:
     xp = jnp.pad(x2, ((0, 0), (0, tk * bk - k)))
     xt = xp.reshape(m, tk, bk)
     xg = jnp.take(xt, jnp.asarray(plan["kk"]), axis=1)      # (M, T, bk)
-    prod = jnp.einsum("mtk,tkn->mtn", xg, arrays["wt"])     # (M, T, bn)
+    prod = jnp.einsum("mtk,tkn->mtn", xg, arrays["wt"],
+                      precision=_HI)                        # (M, T, bn)
     y = jax.ops.segment_sum(prod.swapaxes(0, 1),
                             jnp.asarray(plan["nn"]), num_segments=tn,
                             indices_are_sorted=True)        # (Tn, M, bn)
@@ -123,7 +131,7 @@ def _apply_cond(plan: dict, arrays: dict, x2: jnp.ndarray) -> jnp.ndarray:
             wt = jax.lax.dynamic_slice(w, (ti * bk, tj * bn), (bk, bn))
 
             def dot(acc, xt=xt, wt=wt):
-                return acc + xt @ wt
+                return acc + jnp.dot(xt, wt, precision=_HI)
 
             acc = jax.lax.cond(keep[ti, tj], dot, lambda a: a, acc)
         cols.append(acc)
@@ -140,11 +148,11 @@ def apply_linear(plan: dict, arrays: dict, x: jnp.ndarray) -> jnp.ndarray:
     elif impl == "cond":
         y = _apply_cond(plan, arrays, x2)
     elif impl == "pallas":
-        y = ops.masked_matmul(x2, arrays["w"], arrays["keep"],
-                              block_k=plan["bk"], block_n=plan["bn"])
-        y = y.astype(jnp.float32)
+        y = ops.masked_matmul_padded(x2, arrays["w"], arrays["keep"],
+                                     plan["n"], block_k=plan["bk"],
+                                     block_n=plan["bn"])
     else:
-        y = x2 @ arrays["w"]
+        y = jnp.dot(x2, arrays["w"], precision=_HI)
     if "b" in arrays:
         y = y + arrays["b"]
     return y.reshape(*lead, plan["n"])

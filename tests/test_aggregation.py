@@ -53,10 +53,6 @@ def test_sample_arrivals_statistics():
 
 def test_psum_aggregate_matches_host_aggregate():
     """Device-side Eq. (5) == host Eq. (5) on a 1-axis mesh."""
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = jax.device_count()   # 1 on CPU: degenerate but still exercises psum
@@ -69,7 +65,7 @@ def test_psum_aggregate_matches_host_aggregate():
         return agg.psum_aggregate(jax.tree.map(lambda x: x[0], gs),
                                   ks[0], cs[0], "clients")
 
-    out = jax.jit(shard_map(
+    out = jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("clients"), P("clients"), P("clients")),
         out_specs=P()))(g, k, c)

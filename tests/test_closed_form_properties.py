@@ -127,7 +127,7 @@ def test_newton_round_trips_on_jax_path():
     """The xp=jnp lane (what vmapped fleet cells trace) agrees with numpy
     and round-trips to the same tolerance under x64."""
     import jax
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         p, h = 0.2, 1e-10
         ceiling = p * h / (N0 * np.log(2.0))
         targets = np.array([0.05, 0.5, 0.9]) * ceiling

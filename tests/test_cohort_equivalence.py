@@ -44,7 +44,7 @@ from repro.launch import mesh as MESH
 def x64():
     """Equivalence under float64: the tolerance tests the algorithm, not
     fp32 reduction-order noise."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         yield
 
 

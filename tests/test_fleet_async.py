@@ -37,7 +37,7 @@ def tiny(rounds=6, **kw):
 def x64():
     """Run both engine modes in float64 so the equivalence tolerance tests
     the algorithm, not fp32 reduction-order noise."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         yield
 
 

@@ -137,7 +137,7 @@ def test_engine_ragged_chunk_matches_unchunked():
     exact-sized call instead of zero-weight padded rows that still paid
     for batch generation and a full backward pass."""
     import jax
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         a = run_fleet(tiny(rounds=3))                 # 3 cells, unchunked
         b = run_fleet(tiny(rounds=3, cell_chunk=2))   # 1 full chunk + 1 rem
     np.testing.assert_allclose(a.losses, b.losses, rtol=1e-6, atol=1e-9)

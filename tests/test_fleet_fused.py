@@ -28,7 +28,7 @@ BLOCK = 8
 
 @contextlib.contextmanager
 def x64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         yield
 
 

@@ -10,7 +10,6 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from conftest import make_problem
 from repro.core import closed_form as CF
@@ -21,7 +20,7 @@ from repro.fleet import solver as FS
 
 @pytest.fixture(autouse=True)
 def _x64():
-    with enable_x64():
+    with jax.enable_x64(True):
         yield
 
 

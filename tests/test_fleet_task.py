@@ -36,7 +36,7 @@ from repro.fleet.task import auto_tile_grid, make_task
 
 @contextlib.contextmanager
 def x64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         yield
 
 

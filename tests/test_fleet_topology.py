@@ -41,7 +41,7 @@ from repro.fleet import topology as FT
 
 @contextlib.contextmanager
 def x64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         yield
 
 

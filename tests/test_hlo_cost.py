@@ -16,7 +16,7 @@ def _compiled_text(f, *specs):
 
 def _xla_flops(f, *specs):
     compiled = jax.jit(f).lower(*specs).compile()
-    return HC.xla_cost_analysis(compiled).get("flops", 0.0)
+    return compiled.cost_analysis().get("flops", 0.0)
 
 
 def test_single_matmul_matches_xla():
@@ -110,17 +110,13 @@ def test_collectives_parsed_with_bytes():
     n = jax.device_count()
     from repro.launch import mesh as MESH
     mesh = MESH.make_mesh((n,), ("d",))
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map
 
     def f(x):
         return jax.lax.psum(x, "d")
 
     xs = jax.ShapeDtypeStruct((128,), jnp.float32)
     with mesh:
-        txt = jax.jit(shard_map(f, mesh=mesh, in_specs=P("d"),
+        txt = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("d"),
                                 out_specs=P())).lower(xs).compile().as_text()
     cost = HC.hlo_cost(txt, default_group=n)
     if n > 1:

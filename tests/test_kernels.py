@@ -211,6 +211,33 @@ def test_block_norms_dtypes(dtype):
                                np.asarray(expect, np.float32), rtol=2e-2)
 
 
+@pytest.mark.parametrize("bk,bn", [(72, 192), (24, 40)])
+@pytest.mark.parametrize("transpose_rhs", [False, True])
+def test_masked_matmul_off_tiling_grid(bk, bn, transpose_rhs):
+    """Grids off the (8, 128) tiling (smollm's 72x192) are padded tile by
+    tile to it inside the wrapper; the result is still x @ (w * mask)."""
+    kdim, n = 3 * bk, 4 * bn
+    kx, kw, km = jax.random.split(jax.random.PRNGKey(3), 3)
+    w = jax.random.normal(kw, (kdim, n))
+    mask = (jax.random.uniform(km, (3, 4)) > 0.4).astype(jnp.float32)
+    x = jax.random.normal(kx, (5, n if transpose_rhs else kdim))
+    y = ops.masked_matmul(x, w, mask, block_k=bk, block_n=bn,
+                          transpose_rhs=transpose_rhs, interpret=True)
+    oracle = ref.block_sparse_matmul_t if transpose_rhs \
+        else ref.block_sparse_matmul
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(oracle(x, w, mask, bk, bn)),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_tile_norms_off_tiling_grid():
+    w = jax.random.normal(jax.random.PRNGKey(4), (144, 384))
+    out = ops.tile_norms(w, 72, 192, interpret=True)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref.block_norms(w, 72, 192)),
+                               rtol=1e-5)
+
+
 def test_tile_norms_wrapper_ragged():
     w = jax.random.normal(jax.random.PRNGKey(0), (200, 300))
     out = ops.tile_norms(w)
@@ -241,8 +268,8 @@ def test_block_norms_match_pruning_module():
 def test_decode_attention_shapes(b, h, hkv, hd, s):
     ks = jax.random.split(jax.random.PRNGKey(b * h + s), 4)
     q = jax.random.normal(ks[0], (b, h, hd))
-    k = jax.random.normal(ks[1], (b, s, hkv, hd))
-    v = jax.random.normal(ks[2], (b, s, hkv, hd))
+    k = jax.random.normal(ks[1], (b, hkv, s, hd))
+    v = jax.random.normal(ks[2], (b, hkv, s, hd))
     pos = jax.random.randint(ks[3], (b,), 0, s)
     out = ops.flash_decode(q, k, v, pos, block_s=128)
     expect = ref.decode_attention(q, k, v, pos)
@@ -255,8 +282,8 @@ def test_decode_attention_windowed(window):
     ks = jax.random.split(jax.random.PRNGKey(window), 4)
     b, h, hkv, hd, s = 2, 4, 2, 64, 256
     q = jax.random.normal(ks[0], (b, h, hd))
-    k = jax.random.normal(ks[1], (b, s, hkv, hd))
-    v = jax.random.normal(ks[2], (b, s, hkv, hd))
+    k = jax.random.normal(ks[1], (b, hkv, s, hd))
+    v = jax.random.normal(ks[2], (b, hkv, s, hd))
     pos = jnp.asarray([s - 1, s // 2])
     out = ops.flash_decode(q, k, v, pos, block_s=128, window=window)
     expect = ref.decode_attention(q, k, v, pos, window=window)
@@ -268,8 +295,8 @@ def test_decode_attention_windowed(window):
 def test_decode_attention_dtypes(dtype):
     ks = jax.random.split(jax.random.PRNGKey(9), 3)
     q = jax.random.normal(ks[0], (2, 4, 64)).astype(dtype)
-    k = jax.random.normal(ks[1], (2, 128, 2, 64)).astype(dtype)
-    v = jax.random.normal(ks[2], (2, 128, 2, 64)).astype(dtype)
+    k = jax.random.normal(ks[1], (2, 2, 128, 64)).astype(dtype)
+    v = jax.random.normal(ks[2], (2, 2, 128, 64)).astype(dtype)
     pos = jnp.asarray([100, 60])
     out = ops.flash_decode(q, k, v, pos, block_s=128)
     expect = ref.decode_attention(q, k, v, pos)
@@ -278,12 +305,35 @@ def test_decode_attention_dtypes(dtype):
                                rtol=2e-2, atol=2e-2)
 
 
+@pytest.mark.parametrize("s", [64, 200, 536])
+def test_decode_attention_any_cache_length(s):
+    """A cache length some block divides is read where it lies (no pad of
+    the cache per call); 536 = 8 * 67 has no block in [128, 512] and is
+    padded.  Both match the oracle."""
+    ks = jax.random.split(jax.random.PRNGKey(s), 4)
+    b, h, hkv, hd = 2, 6, 3, 64
+    q = jax.random.normal(ks[0], (b, h, hd))
+    k = jax.random.normal(ks[1], (b, hkv, s, hd))
+    v = jax.random.normal(ks[2], (b, hkv, s, hd))
+    pos = jnp.asarray([s - 1, s // 3], jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda *a: ops.flash_decode(*a))(q, k, v, pos)
+    call, = [e for e in jaxpr.jaxpr.eqns
+             if e.params.get("name") == "decode_attention"]
+    # the kernel reads the caller's k and v themselves, or padded copies
+    in_place = call.invars[1:3] == jaxpr.jaxpr.invars[1:3]
+    assert in_place == (s != 536)
+    out = ops.flash_decode(q, k, v, pos)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(ref.decode_attention(q, k, v, pos)),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_decode_attention_pos_zero():
     """Only the first key visible at pos=0."""
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(ks[0], (1, 2, 64))
-    k = jax.random.normal(ks[1], (1, 128, 1, 64))
-    v = jax.random.normal(ks[2], (1, 128, 1, 64))
+    k = jax.random.normal(ks[1], (1, 1, 128, 64))
+    v = jax.random.normal(ks[2], (1, 1, 128, 64))
     out = ops.flash_decode(q, k, v, jnp.zeros((1,), jnp.int32), block_s=128)
     np.testing.assert_allclose(np.asarray(out)[0, 0], np.asarray(v)[0, 0, 0],
                                rtol=1e-5, atol=1e-5)
@@ -300,8 +350,8 @@ def test_decode_attention_pos_zero():
 def test_flash_prefill_causal(b, s, h, hkv, hd):
     ks = jax.random.split(jax.random.PRNGKey(b * s + h), 3)
     q = jax.random.normal(ks[0], (b, s, h, hd))
-    k = jax.random.normal(ks[1], (b, s, hkv, hd))
-    v = jax.random.normal(ks[2], (b, s, hkv, hd))
+    k = jax.random.normal(ks[1], (b, hkv, s, hd))
+    v = jax.random.normal(ks[2], (b, hkv, s, hd))
     out = ops.flash_prefill(q, k, v, block_q=64, block_s=64)
     expect = ref.prefill_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
@@ -313,8 +363,8 @@ def test_flash_prefill_windowed(window):
     b, s, h, hkv, hd = 1, 256, 4, 2, 64
     ks = jax.random.split(jax.random.PRNGKey(window), 3)
     q = jax.random.normal(ks[0], (b, s, h, hd))
-    k = jax.random.normal(ks[1], (b, s, hkv, hd))
-    v = jax.random.normal(ks[2], (b, s, hkv, hd))
+    k = jax.random.normal(ks[1], (b, hkv, s, hd))
+    v = jax.random.normal(ks[2], (b, hkv, s, hd))
     out = ops.flash_prefill(q, k, v, window=window, block_q=64, block_s=64)
     expect = ref.prefill_attention(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
@@ -326,8 +376,8 @@ def test_flash_prefill_cross_ragged():
     b, s, t, h, hd = 1, 128, 94, 4, 64
     ks = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(ks[0], (b, s, h, hd))
-    k = jax.random.normal(ks[1], (b, t, h, hd))
-    v = jax.random.normal(ks[2], (b, t, h, hd))
+    k = jax.random.normal(ks[1], (b, h, t, hd))
+    v = jax.random.normal(ks[2], (b, h, t, hd))
     out = ops.flash_prefill(q, k, v, causal=False, block_q=64, block_s=64)
     expect = ref.prefill_attention(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
@@ -339,8 +389,8 @@ def test_flash_prefill_dtypes(dtype):
     b, s, h, hkv, hd = 1, 128, 4, 2, 64
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     q = jax.random.normal(ks[0], (b, s, h, hd)).astype(dtype)
-    k = jax.random.normal(ks[1], (b, s, hkv, hd)).astype(dtype)
-    v = jax.random.normal(ks[2], (b, s, hkv, hd)).astype(dtype)
+    k = jax.random.normal(ks[1], (b, hkv, s, hd)).astype(dtype)
+    v = jax.random.normal(ks[2], (b, hkv, s, hd)).astype(dtype)
     out = ops.flash_prefill(q, k, v, block_q=64, block_s=64)
     expect = ref.prefill_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -356,7 +406,8 @@ def test_flash_prefill_matches_model_flash():
     q = jax.random.normal(ks[0], (b, s, h, hd))
     k = jax.random.normal(ks[1], (b, s, hkv, hd))
     v = jax.random.normal(ks[2], (b, s, hkv, hd))
-    kern = ops.flash_prefill(q, k, v, block_q=64, block_s=64)
+    kern = ops.flash_prefill(q, k.transpose(0, 2, 1, 3),
+                             v.transpose(0, 2, 1, 3), block_q=64, block_s=64)
     jaxflash = A.flash_attention(q, k, v, hd ** -0.5, causal=True,
                                  q_chunk=64, kv_chunk=64)
     np.testing.assert_allclose(np.asarray(kern), np.asarray(jaxflash),

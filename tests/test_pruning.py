@@ -175,6 +175,20 @@ def test_block_keep_batched_matches_scalar():
         np.testing.assert_array_equal(np.asarray(keeps[ci]) > 0, tiles)
 
 
+@pytest.mark.parametrize("block", [4, (3, 5)])
+def test_block_rate_one_keeps_nothing(block):
+    """rho = 1: every tile's mass is <= the total, so every tile drops —
+    the keep indicators and the element masks agree."""
+    params = {"w": jax.random.normal(jax.random.PRNGKey(1), (12, 20)),
+              "b": jnp.ones((20,))}
+    state = pruning.block_norm_state(params, block)
+    keep = pruning.block_keep(state, jnp.ones((2,)))
+    assert float(jnp.sum(keep[1])) == 0.0
+    masks = pruning.block_masks(params, 1.0, block=block)
+    assert not bool(jnp.any(masks["w"]))
+    assert bool(jnp.all(masks["b"]))
+
+
 def test_block_norm_state_skips_unprunable_leaves():
     p = _params()
     state = pruning.block_norm_state(p, block=32)

@@ -75,6 +75,26 @@ def test_linear_impls_match_oracle(impl, rho):
                                rtol=2e-5, atol=2e-5)
 
 
+def test_pallas_linear_pads_weight_once():
+    """The pallas layer stores its weight in the kernel's hardware tiling
+    at build (every 16x32 tile zero-padded to 128x128), so an apply pads
+    only the activations."""
+    w = jax.random.normal(jax.random.PRNGKey(0), (50, 70), jnp.float32)
+    keep = jnp.asarray([[1.0, 0.0, 1.0], [0.0, 1.0, 1.0], [1.0, 1.0, 0.0],
+                        [1.0, 0.0, 0.0]])
+    plan, arrays = sparse.make_linear(w, keep, (16, 32), impl="pallas")
+    assert arrays["w"].shape == (4 * 128, 3 * 128)
+    x = jax.random.normal(jax.random.PRNGKey(1), (5, 50), jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda a, x: sparse.apply_linear(plan, a, x))(arrays, x)
+    call, = [e for e in jaxpr.jaxpr.eqns
+             if e.params.get("name") == "block_sparse_matmul"]
+    assert call.invars[1] in jaxpr.jaxpr.invars     # the stored weight
+    got = sparse.apply_linear(plan, arrays, x)
+    want = ops.masked_matmul(x, w, keep, block_k=16, block_n=32)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 @pytest.mark.parametrize("impl", ["gather", "cond"])
 def test_linear_all_pruned_and_all_dense(impl):
     w = jnp.ones((32, 48), jnp.float32)
@@ -135,8 +155,8 @@ def test_decode_attention_head_mask(impl, mask):
     b, h, hkv, hd, s = 3, 6, 3, 8, 40
     ks = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(ks[0], (b, h, hd), jnp.float32)
-    k = jax.random.normal(ks[1], (b, s, hkv, hd), jnp.float32)
-    v = jax.random.normal(ks[2], (b, s, hkv, hd), jnp.float32)
+    k = jax.random.normal(ks[1], (b, hkv, s, hd), jnp.float32)
+    v = jax.random.normal(ks[2], (b, hkv, s, hd), jnp.float32)
     pos = jnp.array([0, 17, 39], jnp.int32)
     hm = None if mask is None else np.asarray(mask, np.float32)
     got = ops.flash_decode(q, k, v, pos, block_s=16, head_mask=hm, impl=impl)
@@ -151,8 +171,8 @@ def test_prefill_attention_head_mask(impl, mask):
     b, s, h, hkv, hd = 2, 24, 4, 2, 8
     ks = jax.random.split(jax.random.PRNGKey(6), 3)
     q = jax.random.normal(ks[0], (b, s, h, hd), jnp.float32)
-    k = jax.random.normal(ks[1], (b, s, hkv, hd), jnp.float32)
-    v = jax.random.normal(ks[2], (b, s, hkv, hd), jnp.float32)
+    k = jax.random.normal(ks[1], (b, hkv, s, hd), jnp.float32)
+    v = jax.random.normal(ks[2], (b, hkv, s, hd), jnp.float32)
     hm = None if mask is None else np.asarray(mask, np.float32)
     got = ops.flash_prefill(q, k, v, causal=True, block_q=8, block_s=8,
                             head_mask=hm, impl=impl)

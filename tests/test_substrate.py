@@ -53,6 +53,17 @@ def test_checkpoint_roundtrip(tmp_path):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b))
 
 
+def test_checkpoint_bfloat16_roundtrip(tmp_path):
+    """bf16 leaves (smollm's param dtype) come back bit-exact."""
+    w = jax.random.normal(jax.random.PRNGKey(0), (4, 8)).astype(jnp.bfloat16)
+    path = os.path.join(tmp_path, "bf16.npz")
+    checkpoint.save(path, {"w": w})
+    back = checkpoint.restore(path, {"w": jnp.zeros((4, 8), jnp.bfloat16)})
+    assert back["w"].dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(back["w"], np.float32),
+                                  np.asarray(w, np.float32))
+
+
 def test_checkpoint_shape_mismatch_raises(tmp_path):
     path = os.path.join(tmp_path, "ckpt.npz")
     checkpoint.save(path, {"w": jnp.zeros((2, 3))})
@@ -129,6 +140,13 @@ def test_collective_stats_ignores_non_collectives():
     assert st.total_bytes == 0 and not st.counts
 
 
+def test_chip_peaks_known_kind_and_unknown_raises():
+    v5e = RF.chip_peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw, v5e.hbm_bytes) == (197e12, 819e9, 16e9)
+    with pytest.raises(KeyError, match="no published peaks"):
+        RF.chip_peaks("cpu")
+
+
 def test_roofline_report_terms():
     rep = RF.RooflineReport(
         arch="x", shape="train_4k", mesh="16x16", chips=256,
@@ -137,7 +155,7 @@ def test_roofline_report_terms():
         collective_bytes_per_chip=50e9 * 0.001,  # 1 ms collective
         peak_memory_per_chip=1 << 30, argument_bytes=0, output_bytes=0,
         temp_bytes=0, collectives={}, model_flops=197e12 * 0.010 * 256 * 0.5,
-        wall_s=1.0)
+        wall_s=1.0, device_kind="TPU v5 lite")
     assert rep.t_compute == pytest.approx(0.010)
     assert rep.t_memory == pytest.approx(0.005)
     assert rep.t_collective == pytest.approx(0.001)
@@ -162,3 +180,33 @@ def test_data_pspec_batch_dim():
     mesh = jax.make_mesh((1, 1), ("data", "model"))
     spec = SH.data_pspec((8, 128), mesh, batch_dim=0)
     assert len(spec) == 2
+
+
+# ---------------------------------------------------------------------------
+# Compile cache placement
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def restore_cache_dir():
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_env_var_wins(monkeypatch, restore_cache_dir):
+    from repro.launch import compile_cache as CC
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(CC.ENV_VAR, "/elsewhere/cache")
+    assert CC.enable_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_default_is_fixed_in_checkout(monkeypatch,
+                                                    restore_cache_dir):
+    from repro.launch import compile_cache as CC
+    monkeypatch.delenv(CC.ENV_VAR, raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(root, ".jax_cache")
+    assert CC.enable_compile_cache() == want
+    assert CC.enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
