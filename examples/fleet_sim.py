@@ -35,8 +35,8 @@ import time
 import numpy as np
 
 from repro.fleet import (AsyncConfig, FleetConfig, FleetTopology,
-                         HexInterference, ScheduleConfig, SpanRecorder,
-                         TelemetryConfig, make_task, run_fleet,
+                         HexInterference, ScheduleConfig, TelemetryConfig,
+                         make_task, process_recorder, run_fleet,
                          sink_for_path)
 
 
@@ -122,7 +122,8 @@ def main() -> None:
     ap.add_argument("--telemetry-bins", type=int, default=16,
                     help="histogram bins of the in-scan telemetry")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="write build/run/finalize host phase spans as "
+                    help="write the build stages, JAX's trace/lower/compile "
+                         "spans and the run/finalize host spans as "
                          "Chrome-trace JSON (chrome://tracing / Perfetto)")
     args = ap.parse_args()
 
@@ -191,7 +192,8 @@ def main() -> None:
           f"task={args.task}, kernel={kernel}, geometry={geo_tag}, "
           f"{tier_tag}")
     sink = sink_for_path(args.telemetry_out) if args.telemetry_out else None
-    recorder = SpanRecorder() if args.trace_out else None
+    # the process recorder also holds JAX's trace/lower/compile spans
+    recorder = process_recorder() if args.trace_out else None
     t0 = time.time()
     res = run_fleet(cfg, mesh=mesh, progress=True, mode=mode, sink=sink,
                     recorder=recorder)

@@ -18,7 +18,9 @@ Observability is opt-in (`telemetry`): ``FleetConfig(telemetry=
 TelemetryConfig(...))`` rides fixed-size per-round summaries (histograms,
 staleness / gradient drift, solver diagnostics) through the scan into
 ``FleetResult.telemetry``; ``SpanRecorder`` captures host phase spans as
-Chrome-trace JSON, and ``TelemetrySink`` implementations (memory / JSONL
+Chrome-trace JSON (``process_recorder()`` is the process-wide one, which
+also holds JAX's trace/lower/compile spans; ``compile_report()`` sums
+them per program), and ``TelemetrySink`` implementations (memory / JSONL
 / CSV) receive per-round records via ``run_fleet(..., sink=...)``.
 """
 
@@ -29,7 +31,8 @@ from repro.fleet.scheduler import AsyncConfig, ScheduleConfig  # noqa: F401
 from repro.fleet.solver import SolverConfig  # noqa: F401
 from repro.fleet.telemetry import (  # noqa: F401
     CSVSink, JSONLSink, MemorySink, SpanRecorder, TelemetryConfig,
-    TelemetrySink, emit_result, sink_for_path)
+    TelemetrySink, compile_report, emit_result, process_recorder,
+    sink_for_path)
 from repro.fleet.task import (  # noqa: F401
     FleetTask, LinearRegressionTask, SyntheticMLPTask, TransformerTask,
     make_task)

@@ -80,6 +80,7 @@ bounding the control pass's working set at million-client fleets.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import warnings
 from typing import Any, NamedTuple, Optional
@@ -341,15 +342,25 @@ def _client_grad(task: TASK.FleetTask, params: PyTree, rho_i: jnp.ndarray,
     gradient re-masked (exactly the 5-client path's client_grad).  The
     mask rule follows ``mask_kind`` (default ``cfg.mask_kind``):
     unstructured magnitude pruning (paper-style) or block-norm threshold
-    masks on the task's tile grid (the fused kernel's)."""
-    if (mask_kind or cfg.mask_kind) == "block":
-        masks = pruning.block_masks(params, rho_i,
-                                    block=task.tile_grid(params))
-    else:
-        masks = pruning.magnitude_masks(params, rho_i)
-    pruned = pruning.apply_masks(params, masks)
-    loss, g = jax.value_and_grad(lambda p: task.loss(p, batch))(pruned)
-    return loss, pruning.apply_masks(g, masks)
+    masks on the task's tile grid (the fused kernel's).
+
+    Callers ``vmap`` it over clients, and JAX names the first scope under
+    a ``vmap`` ``vmap(<scope>)`` in the ``op_name`` path: ``fleet.client``
+    takes that place, so ``fleet.mask`` and ``fleet.backprop`` keep their
+    own names there."""
+    with jax.named_scope("fleet.client"):
+        with jax.named_scope("fleet.mask"):
+            if (mask_kind or cfg.mask_kind) == "block":
+                masks = pruning.block_masks(params, rho_i,
+                                            block=task.tile_grid(params))
+            else:
+                masks = pruning.magnitude_masks(params, rho_i)
+            pruned = pruning.apply_masks(params, masks)
+        with jax.named_scope("fleet.backprop"):
+            loss, g = jax.value_and_grad(
+                lambda p: task.loss(p, batch))(pruned)
+        with jax.named_scope("fleet.mask"):
+            return loss, pruning.apply_masks(g, masks)
 
 
 def _kernel_impl(cfg: FleetConfig) -> str:
@@ -449,16 +460,18 @@ def _fleet_grads(task: TASK.FleetTask, params: PyTree, rho: jnp.ndarray,
         data_leaves, data_def = jax.tree_util.tree_flatten(data)
         if cohort is not None:
             flat = idx.reshape(-1)
-            arrays += [a[flat].reshape((c, i) + a.shape[1:])
-                       for a in data_leaves]
+            with jax.named_scope("fleet.data"):
+                arrays += [a[flat].reshape((c, i) + a.shape[1:])
+                           for a in data_leaves]
         else:
             arrays += [a.reshape((c, i) + a.shape[1:]) for a in data_leaves]
 
     def batches(c_idx, extra):
-        if extra:
-            leaves = [a.reshape((-1,) + a.shape[2:]) for a in extra]
-            return jax.tree_util.tree_unflatten(data_def, leaves)
-        return batch_fn(c_idx.reshape(-1))
+        with jax.named_scope("fleet.data"):
+            if extra:
+                leaves = [a.reshape((-1,) + a.shape[2:]) for a in extra]
+                return jax.tree_util.tree_unflatten(data_def, leaves)
+            return batch_fn(c_idx.reshape(-1))
 
     if cfg.kernel == "reference":
         def step(c_idx, c_rho, c_w, c_lw, *extra):
@@ -475,14 +488,16 @@ def _fleet_grads(task: TASK.FleetTask, params: PyTree, rho: jnp.ndarray,
     else:
         # once per round: the full ranking of every layer's tile norms —
         # per-client masks below are one searchsorted each
-        prep = task.kernel_prepare(params)
+        with jax.named_scope("fleet.mask"):
+            prep = task.kernel_prepare(params)
 
         def step(c_idx, c_rho, c_w, c_lw, *extra):
             batch = _constrain_clients(batches(c_idx, extra), mesh)
             w_flat = c_w.reshape(-1)
-            g, losses = task.kernel_grads(params, prep, batch,
-                                          c_rho.reshape(-1), w_flat,
-                                          impl=_kernel_impl(cfg))
+            with jax.named_scope("fleet.backprop"):
+                g, losses = task.kernel_grads(params, prep, batch,
+                                              c_rho.reshape(-1), w_flat,
+                                              impl=_kernel_impl(cfg))
             lw_flat = c_lw.reshape(-1)
             return (g, jnp.sum(w_flat), jnp.sum(losses * lw_flat),
                     jnp.sum(lw_flat))
@@ -606,34 +621,36 @@ def _make_control_fn(cfg: FleetConfig, pop: TOPO.ClientPopulation,
         with jax.named_scope("fleet.channel"):
             chan = geo.round_channel(k_fade, pop, cfg.topology)
         h_up, h_down = chan.h_up, chan.h_down
-        if use_cohort:
-            mask, cohort = SCHED.participation_cohort(
-                k_part, cfg.schedule, pop.num_samples)
-        else:
-            mask = SCHED.participation_mask(k_part, cfg.schedule,
-                                            pop.num_samples)
-            cohort = None
-        ho = SCHED.handover_mask(chan.served_home, cfg.schedule)
-        if ho is not None:
-            mask = mask * ho
-        # The round's Eq.-(11) surrogate coefficient is the *scheduled*
-        # subset's: under partial participation each cell's one-round
-        # subproblem is over the drawn clients, not the full census.
-        m_round = CF.surrogate_m(pop.num_samples, cfg.smoothness.beta,
-                                 cfg.smoothness.xi1, cfg.smoothness.xi2,
-                                 cfg.smoothness.weight_bound, xp=jnp,
-                                 mask=mask)
+        with jax.named_scope("fleet.schedule"):
+            if use_cohort:
+                mask, cohort = SCHED.participation_cohort(
+                    k_part, cfg.schedule, pop.num_samples)
+            else:
+                mask = SCHED.participation_mask(k_part, cfg.schedule,
+                                                pop.num_samples)
+                cohort = None
+            ho = SCHED.handover_mask(chan.served_home, cfg.schedule)
+            if ho is not None:
+                mask = mask * ho
+            # The round's Eq.-(11) surrogate coefficient is the *scheduled*
+            # subset's: under partial participation each cell's one-round
+            # subproblem is over the drawn clients, not the full census.
+            m_round = CF.surrogate_m(pop.num_samples, cfg.smoothness.beta,
+                                     cfg.smoothness.xi1, cfg.smoothness.xi2,
+                                     cfg.smoothness.weight_bound, xp=jnp,
+                                     mask=mask)
 
-        # Broadcast latency is fixed before the uplink control problem, so
-        # a configured round deadline caps the solver's t~ by what remains
-        # after the downlink + aggregation (time-triggered FL).
-        r_d = CF.downlink_rate(b_hz, w.tx_power_bs_w, h_down, n0, xp=jnp)
-        t_d = jnp.max(jnp.where(mask > 0, w.model_bits / r_d, 0.0), axis=-1,
-                      keepdims=True)
-        cap = None
-        if cfg.schedule.has_deadline:
-            cap = jnp.maximum(cfg.schedule.round_deadline_s
-                              - w.aggregation_latency_s - t_d[..., 0], 0.0)
+            # Broadcast latency is fixed before the uplink control problem,
+            # so a configured round deadline caps the solver's t~ by what
+            # remains after the downlink + aggregation (time-triggered FL).
+            r_d = CF.downlink_rate(b_hz, w.tx_power_bs_w, h_down, n0, xp=jnp)
+            t_d = jnp.max(jnp.where(mask > 0, w.model_bits / r_d, 0.0),
+                          axis=-1, keepdims=True)
+            cap = None
+            if cfg.schedule.has_deadline:
+                cap = jnp.maximum(cfg.schedule.round_deadline_s
+                                  - w.aggregation_latency_s - t_d[..., 0],
+                                  0.0)
 
         solve_kw = dict(
             bandwidth_hz=b_hz, noise_psd=n0, waterfall_m0=w.waterfall_m0,
@@ -867,9 +884,11 @@ def _cell_grad_step(task: TASK.FleetTask, cfg: FleetConfig, params_c: PyTree,
         g = jax.tree.map(
             lambda gg: jnp.einsum("c,c...->...", agg_w_c, gg), grads)
     else:
-        prep = task.kernel_prepare(params_c)
-        g, losses = task.kernel_grads(params_c, prep, batch_c, rho_c,
-                                      agg_w_c, impl=_kernel_impl(cfg))
+        with jax.named_scope("fleet.mask"):
+            prep = task.kernel_prepare(params_c)
+        with jax.named_scope("fleet.backprop"):
+            g, losses = task.kernel_grads(params_c, prep, batch_c, rho_c,
+                                          agg_w_c, impl=_kernel_impl(cfg))
     return (g, jnp.sum(agg_w_c), jnp.sum(losses * sched_w_c),
             jnp.sum(sched_w_c))
 
@@ -916,10 +935,11 @@ def _make_two_tier_round_fn(cfg: FleetConfig, task: TASK.FleetTask,
     def cell_body(_, inp):
         theta_c, idx_c, rho_c, aggw_c, schedw_c = inp[:5]
         extra = inp[5:]
-        if extra:
-            batch_c = jax.tree_util.tree_unflatten(data_def, list(extra))
-        else:
-            batch_c = batch_fn(idx_c)
+        with jax.named_scope("fleet.data"):
+            if extra:
+                batch_c = jax.tree_util.tree_unflatten(data_def, list(extra))
+            else:
+                batch_c = batch_fn(idx_c)
         g, wsum, lsum, lw = _cell_grad_step(task, cfg, theta_c, rho_c,
                                             aggw_c, schedw_c, batch_c)
         denom = jnp.where(wsum > 0, wsum, 1.0)
@@ -1198,10 +1218,12 @@ def _make_async_step(cfg: FleetConfig, task: TASK.FleetTask, state: PyTree,
 
                 def compute(s=s, in_slot=in_slot):
                     p_s = jax.tree.map(lambda a: a[s], hist)
-                    prep = task.kernel_prepare(p_s)
-                    g, l = task.kernel_grads(p_s, prep, batch, rho_sel,
-                                             w_merge * in_slot,
-                                             impl=_kernel_impl(cfg))
+                    with jax.named_scope("fleet.mask"):
+                        prep = task.kernel_prepare(p_s)
+                    with jax.named_scope("fleet.backprop"):
+                        g, l = task.kernel_grads(p_s, prep, batch, rho_sel,
+                                                 w_merge * in_slot,
+                                                 impl=_kernel_impl(cfg))
                     return g, jnp.where(in_slot, l, 0.0).astype(ldtype)
 
                 shapes = jax.eval_shape(compute)
@@ -1445,32 +1467,42 @@ class Simulation:
         )
 
 
-def _build_common(cfg: FleetConfig, mesh=None):
+def _span(recorder: Optional[TEL.SpanRecorder], name: str):
+    return recorder.span(name) if recorder is not None \
+        else contextlib.nullcontext()
+
+
+def _build_common(cfg: FleetConfig, mesh=None,
+                  recorder: Optional[TEL.SpanRecorder] = None):
     """Shared setup of the scan engine and the host-stepped reference path:
     resolve the task, drop the population, build data/model, and (when the
-    task knows its physical size) override the wireless model bits D_M."""
+    task knows its physical size) override the wireless model bits D_M.
+    With a ``recorder`` each stage is a ``fleet.build.*`` span."""
     task = resolve_task(cfg)
     geo = resolve_geometry(cfg)
     topo = cfg.topology
     root = jax.random.PRNGKey(cfg.seed)
     k_pop, k_task, k_init, k_test, k_data, k_rounds = jax.random.split(root, 6)
 
-    pop = geo.make_population(k_pop, topo, cfg.wireless.tx_power_ue_w)
-    state = task.build(k_task, k_test)
-    params = task.init_params(k_init)
-
-    mb = task.model_bits(params)
+    with _span(recorder, "fleet.build.population"):
+        pop = geo.make_population(k_pop, topo, cfg.wireless.tx_power_ue_w)
+        pop = _shard_cells(pop, mesh)
+    with _span(recorder, "fleet.build.task"):
+        state = task.build(k_task, k_test)
+    with _span(recorder, "fleet.build.init"):
+        params = task.init_params(k_init)
+        mb = task.model_bits(params)
     if mb is not None:
         cfg = dataclasses.replace(
             cfg, wireless=cfg.wireless.replace(model_bits=float(mb)))
 
-    pop = _shard_cells(pop, mesh)
     keys = jax.random.split(k_rounds, cfg.rounds + 1)
     return cfg, task, state, params, pop, k_data, keys
 
 
-def build_simulation(cfg: FleetConfig, mesh=None,
-                     mode: str = "sync") -> Simulation:
+def build_simulation(cfg: FleetConfig, mesh=None, mode: str = "sync",
+                     recorder: Optional[TEL.SpanRecorder] = None
+                     ) -> Simulation:
     """Drop the fleet, build the data/model, jit the round/event scan.
 
     Args:
@@ -1481,6 +1513,13 @@ def build_simulation(cfg: FleetConfig, mesh=None,
         axis of the gradient batch is constrained to it inside the round.
       mode: ``"sync"`` (FedSGD barrier rounds) or ``"async"`` (FedBuff
         buffered events; see ``FleetConfig.async_config``).
+      recorder: the ``telemetry.SpanRecorder`` that receives the
+        ``fleet.build`` span and its stages ``fleet.build.population``,
+        ``.task``, ``.init`` and ``.round`` (default: the process
+        recorder, ``telemetry.process_recorder()``).  They are host
+        times: nothing waits for the device here, so device work a
+        stage dispatches may finish after its span closes.  The round
+        program itself compiles on the first ``simulate`` call.
 
     Returns:
       A ``Simulation`` whose ``simulate(params, round_keys)`` runs
@@ -1506,7 +1545,23 @@ def build_simulation(cfg: FleetConfig, mesh=None,
         raise ValueError(
             f"control_chunk must be >= 0 (0 = solve all cells at once), "
             f"got {cfg.control_chunk}")
-    cfg, task, state, params, pop, k_data, keys = _build_common(cfg, mesh)
+    rec = recorder if recorder is not None else TEL.process_recorder()
+    with rec.span("fleet.build", mode=mode,
+                  clients=cfg.topology.num_clients):
+        cfg, task, state, params, pop, k_data, keys = _build_common(
+            cfg, mesh, rec)
+        with rec.span("fleet.build.round"):
+            simulate, round_keys = _make_simulate(cfg, task, state, pop,
+                                                  k_data, keys, mesh, mode)
+    return Simulation(cfg=cfg, simulate=simulate, params=params,
+                      round_keys=round_keys, num_samples=pop.num_samples,
+                      mode=mode, two_tier=cfg.cloud_period >= 1)
+
+
+def _make_simulate(cfg: FleetConfig, task: TASK.FleetTask, state: PyTree,
+                   pop: TOPO.ClientPopulation, k_data: jax.Array,
+                   keys: jnp.ndarray, mesh, mode: str):
+    """(jitted ``simulate(params, round_keys)``, its round keys)."""
     topo = cfg.topology
     two_tier = cfg.cloud_period >= 1
 
@@ -1561,9 +1616,7 @@ def build_simulation(cfg: FleetConfig, mesh=None,
 
         round_keys = keys
 
-    return Simulation(cfg=cfg, simulate=simulate, params=params,
-                      round_keys=round_keys, num_samples=pop.num_samples,
-                      mode=mode, two_tier=two_tier)
+    return simulate, round_keys
 
 
 def run_fleet(cfg: FleetConfig, mesh=None, progress: bool = False,
@@ -1583,9 +1636,10 @@ def run_fleet(cfg: FleetConfig, mesh=None, progress: bool = False,
       sink: optional ``telemetry.TelemetrySink``; the run's header and
         per-round records (``telemetry.round_records``) are emitted into
         it after the scan returns (the sink is not closed).
-      recorder: optional ``telemetry.SpanRecorder``; the build / simulate
-        / finalize phases are recorded as wall-clock spans (exportable as
-        Chrome-trace JSON via ``recorder.write``).
+      recorder: the ``telemetry.SpanRecorder`` that records the
+        ``fleet.build`` (with its stages), ``fleet.simulate`` and
+        ``fleet.finalize`` wall-clock spans (exportable as Chrome-trace
+        JSON via ``recorder.write``); default: the process recorder.
 
     Returns:
       A ``FleetResult``; trajectories are indexed by round (sync) or
@@ -1593,10 +1647,8 @@ def run_fleet(cfg: FleetConfig, mesh=None, progress: bool = False,
       ``result.telemetry`` carries the in-scan summaries when
       ``cfg.telemetry`` is set.
     """
-    rec = recorder if recorder is not None else TEL.SpanRecorder()
-    with rec.span("fleet.build", mode=mode,
-                  clients=cfg.topology.num_clients):
-        sim = build_simulation(cfg, mesh=mesh, mode=mode)
+    rec = recorder if recorder is not None else TEL.process_recorder()
+    sim = build_simulation(cfg, mesh=mesh, mode=mode, recorder=rec)
     with rec.span("fleet.simulate", rounds=cfg.rounds):
         carry, metrics = sim.simulate(sim.params, sim.round_keys)
         jax.block_until_ready(metrics)

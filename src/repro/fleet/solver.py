@@ -204,41 +204,47 @@ def solve_cell(h_up: jnp.ndarray, num_samples: jnp.ndarray,
         learning = m * jnp.sum(mask * k * (q + k * rho), axis=-1)
         return (1.0 - lam) * deadline + lam * learning
 
+    # One alternation in three named parts (``fleet.solve.vertex`` /
+    # ``.bandwidth`` / ``.cost``), so a device profile splits Algorithm 1.
     def body(state):
         bw, dl, rho, prev_cost, done, iters = state
-        t_np = no_prune_latency(bw)
-        dl2, rho2 = CF.pruning_vertex(t_np, k, lam, m, max_prune, xp=jnp,
-                                      mask=mask)
-        if deadline_cap is not None:
-            dl2 = jnp.minimum(dl2, deadline_cap)
-            rho2 = jnp.minimum(CF.prune_rates_for_deadline(t_np, dl2, xp=jnp),
-                               max_prune) * mask
-        bw2 = CF.bandwidth_for_deadline(
-            rho2, dl2, k, cpu_hz, cycles_per_sample, model_bits, tx_power,
-            h_up, noise_psd, iters=solver.bw_iters, xp=jnp)
-        if deadline_cap is not None:  # unschedulable at rho^max: sit out
-            bw2 = jnp.where(jnp.isfinite(bw2), bw2, 0.0)
+        with jax.named_scope("fleet.solve.vertex"):
+            t_np = no_prune_latency(bw)
+            dl2, rho2 = CF.pruning_vertex(t_np, k, lam, m, max_prune,
+                                          xp=jnp, mask=mask)
+            if deadline_cap is not None:
+                dl2 = jnp.minimum(dl2, deadline_cap)
+                rho2 = jnp.minimum(
+                    CF.prune_rates_for_deadline(t_np, dl2, xp=jnp),
+                    max_prune) * mask
+        with jax.named_scope("fleet.solve.bandwidth"):
+            bw2 = CF.bandwidth_for_deadline(
+                rho2, dl2, k, cpu_hz, cycles_per_sample, model_bits,
+                tx_power, h_up, noise_psd, iters=solver.bw_iters, xp=jnp)
+            if deadline_cap is not None:  # unschedulable at rho^max: sit out
+                bw2 = jnp.where(jnp.isfinite(bw2), bw2, 0.0)
+                bw2 = jnp.where(participating, bw2, 0.0)
+                # A binding cap voids Lemma 2's feasibility guarantee: the
+                # deadline-meeting minimum can oversubscribe B.  Keep the
+                # max-cardinality schedulable subset (ascending-demand
+                # prefix) and sideline the rest for this round.
+                order = jnp.argsort(bw2)
+                fits = jnp.cumsum(jnp.take(bw2, order)) \
+                    <= bandwidth_hz * (1.0 + 1e-9)
+                keep = jnp.zeros_like(bw2).at[order].set(
+                    fits.astype(bw2.dtype))
+                bw2 = bw2 * keep
             bw2 = jnp.where(participating, bw2, 0.0)
-            # A binding cap voids Lemma 2's feasibility guarantee: the
-            # deadline-meeting minimum can oversubscribe B.  Keep the
-            # max-cardinality schedulable subset (ascending-demand prefix)
-            # and sideline the rest for this round.
-            order = jnp.argsort(bw2)
-            fits = jnp.cumsum(jnp.take(bw2, order)) \
-                <= bandwidth_hz * (1.0 + 1e-9)
-            keep = jnp.zeros_like(bw2).at[order].set(
-                fits.astype(bw2.dtype))
-            bw2 = bw2 * keep
-        bw2 = jnp.where(participating, bw2, 0.0)
-        cost = inner_cost(dl2, bw2, rho2)
-        conv = jnp.abs(prev_cost - cost) <= eff_rtol * jnp.maximum(
-            jnp.abs(cost), 1.0)
-        bw = jnp.where(done, bw, bw2)
-        dl = jnp.where(done, dl, dl2)
-        rho = jnp.where(done, rho, rho2)
-        prev_cost = jnp.where(done, prev_cost, cost)
-        iters = iters + jnp.where(done, 0, 1)
-        return bw, dl, rho, prev_cost, done | conv, iters
+        with jax.named_scope("fleet.solve.cost"):
+            cost = inner_cost(dl2, bw2, rho2)
+            conv = jnp.abs(prev_cost - cost) <= eff_rtol * jnp.maximum(
+                jnp.abs(cost), 1.0)
+            bw = jnp.where(done, bw, bw2)
+            dl = jnp.where(done, dl, dl2)
+            rho = jnp.where(done, rho, rho2)
+            prev_cost = jnp.where(done, prev_cost, cost)
+            iters = iters + jnp.where(done, 0, 1)
+            return bw, dl, rho, prev_cost, done | conv, iters
 
     bw0 = mask * (bandwidth_hz / n_part)
     # A freeze threshold below the compute dtype's resolution never fires

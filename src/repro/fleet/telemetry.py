@@ -1,8 +1,9 @@
 """Fleet telemetry substrate: in-scan summaries, trace spans, sinks.
 
-Three observability layers, all opt-in (``FleetConfig(telemetry=...)`` is
-``None`` by default and the default path is bit-identical to a build
-without this module):
+Three observability layers.  The in-scan summaries are opt-in
+(``FleetConfig(telemetry=...)`` is ``None`` by default and the default
+path is bit-identical to a build without this module); scopes cost
+nothing on the device, and host spans a few microseconds each:
 
 * **In-scan deep telemetry** — ``TelemetryConfig`` selects fixed-size
   per-round summaries that ride the engine's ``lax.scan`` as extra
@@ -15,13 +16,43 @@ without this module):
   — bin edges are config constants, so a million-client round emits the
   same few-KB summary as a 5-UE round.
 
-* **Trace spans** — ``SpanRecorder`` wraps host-side phases
-  (build / compile / run / finalize) in ``jax.profiler.TraceAnnotation``
-  and records wall-clock spans exportable as Chrome-trace JSON
-  (``chrome://tracing`` / Perfetto).  Inside the compiled program the
-  engine's phases are annotated with ``jax.named_scope`` (solve /
-  gradient / merge / eval / cloud_merge), so device profiles group by
-  phase too.
+* **Trace spans** — inside the compiled program the engine's phases
+  carry ``jax.named_scope`` labels, metadata only (the optimized program
+  is the same with or without them); a device profile maps each op to
+  its scope through the compiled text's ``op_name``.  Top-level phases
+  and the scopes nested in them:
+
+  ============================  ===========================================
+  scope                         what runs under it
+  ============================  ===========================================
+  ``fleet.channel``             fading draw (``CellGeometry.round_channel``)
+  ``fleet.schedule``            participation draw / cohort, handover,
+                                Eq.-(11) ``surrogate_m``, downlink time,
+                                deadline cap
+  ``fleet.solve``               Algorithm 1 (gathers, scatters, final PER)
+  ``  fleet.solve.vertex``      Prop.-1 vertex walk, cap re-derivation
+  ``  fleet.solve.bandwidth``   Eq.-(21) inversion, cap admission
+  ``  fleet.solve.cost``        Eq.-(14a) cost, freeze test, state selects
+  ``fleet.gradient``            client gradients and the Eq.-(5) sum
+  ``  fleet.data``              client batches: regenerated or gathered
+  ``  fleet.mask``              masks built and applied (fused:
+                                ``kernel_prepare``)
+  ``  fleet.backprop``          forward and backward (fused:
+                                ``kernel_grads``)
+  ``fleet.merge``               the server step
+  ``fleet.cloud_merge``         two-tier edge/cloud merge
+  ``fleet.eval``                the task's eval metrics
+  ============================  ===========================================
+
+  On the host, ``SpanRecorder`` records wall-clock spans (also entered
+  as ``jax.profiler.TraceAnnotation``) and counts, exportable as
+  Chrome-trace JSON (``chrome://tracing`` / Perfetto).
+  ``process_recorder()`` is the process-wide one: ``build_simulation``
+  records ``fleet.build`` and its stages into it, and on first use it
+  listens to ``jax.monitoring`` for every trace, lowering and compile
+  (``jax.trace`` / ``jax.lower`` / ``jax.compile``, with the program's
+  name) and every compile-cache hit and write.  ``compile_report()``
+  says which program compiled how often and for how long.
 
 * **Sinks** — the tiny ``TelemetrySink`` protocol (``emit(record)`` /
   ``close()``) with in-memory, JSONL and CSV implementations; the
@@ -33,6 +64,7 @@ See ``docs/observability.md`` for semantics and usage.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import csv
 import dataclasses
@@ -40,7 +72,7 @@ import json
 import os
 import threading
 import time
-from typing import Any, Optional, Protocol, runtime_checkable
+from typing import Any, NamedTuple, Optional, Protocol, runtime_checkable
 
 import jax
 import jax.numpy as jnp
@@ -214,47 +246,112 @@ def split_metrics(metrics: dict) -> tuple[dict, Optional[dict]]:
 
 
 # ---------------------------------------------------------------------------
-# Trace spans (host wall-clock; Chrome-trace JSON export)
+# Trace spans and counts (host wall clock; Chrome-trace JSON export)
 # ---------------------------------------------------------------------------
 
+class Span(NamedTuple):
+    """One recorded span: wall-clock nanoseconds (``time.time_ns``), the
+    thread that recorded it, and its arguments.  A count is a span with
+    ``end_ns == start_ns``."""
+
+    name: str
+    start_ns: int
+    end_ns: int
+    tid: int
+    args: Optional[dict] = None
+
+
 class SpanRecorder:
-    """Record named wall-clock spans; export as Chrome-trace JSON.
+    """Record named wall-clock spans and counts; export as Chrome-trace JSON.
+
+    Stamps are ``time.time_ns()``: the clock of JAX's own compile events
+    and of a ``jax.profiler`` capture's host events (which the capture
+    stores relative to its ``profile_start_time``), so the three lie
+    side by side.  The newest ``CAPACITY`` spans are kept; per-name
+    totals (count, seconds) and per-program totals of spans that carry a
+    ``program`` argument cover every span ever recorded.
 
     Each ``span`` also enters a ``jax.profiler.TraceAnnotation`` so the
     phase shows up in a ``jax.profiler.trace`` capture when one is
     active.  Spans may nest; events carry the thread id, so the Chrome
     trace viewer (``chrome://tracing`` or Perfetto) renders nesting
-    correctly.  Timestamps are microseconds relative to recorder
-    construction.
+    correctly.
     """
 
+    CAPACITY = 4096
+
     def __init__(self):
-        self._t0 = time.perf_counter()
         self._lock = threading.Lock()
-        self.events: list[dict] = []
+        self._spans: collections.deque = collections.deque(
+            maxlen=self.CAPACITY)
+        self._totals: dict[str, list] = {}
+        self._programs: dict[tuple[str, str], list] = {}
 
     @contextlib.contextmanager
     def span(self, name: str, **args):
-        start = time.perf_counter()
-        try:
-            annotation = jax.profiler.TraceAnnotation(name)
-        except Exception:           # pragma: no cover - profiler unavailable
-            annotation = contextlib.nullcontext()
-        with annotation:
+        start = time.time_ns()
+        with jax.profiler.TraceAnnotation(name):
             try:
                 yield self
             finally:
-                end = time.perf_counter()
-                event = {
-                    "name": name, "ph": "X", "cat": "fleet",
-                    "ts": (start - self._t0) * 1e6,
-                    "dur": (end - start) * 1e6,
-                    "pid": os.getpid(), "tid": threading.get_ident(),
-                }
-                if args:
-                    event["args"] = args
-                with self._lock:
-                    self.events.append(event)
+                self.record(name, start, time.time_ns(), **args)
+
+    def record(self, name: str, start_ns: int, end_ns: int, **args) -> None:
+        """Add a finished span (or, with ``end_ns == start_ns``, a count)."""
+        sp = Span(name, int(start_ns), int(end_ns), threading.get_ident(),
+                  args or None)
+        seconds = (sp.end_ns - sp.start_ns) * 1e-9
+        with self._lock:
+            self._spans.append(sp)
+            tot = self._totals.setdefault(name, [0, 0.0])
+            tot[0] += 1
+            tot[1] += seconds
+            program = args.get("program")
+            if program is not None:
+                per = self._programs.setdefault((name, program),
+                                                [0, seconds, 0.0])
+                per[0] += 1
+                per[2] += seconds
+
+    def count(self, name: str) -> None:
+        """Record one occurrence of ``name`` at this instant."""
+        now = time.time_ns()
+        self.record(name, now, now)
+
+    def spans(self, prefix: str = "") -> list[Span]:
+        """The kept spans whose name starts with ``prefix``, oldest first."""
+        with self._lock:
+            return [s for s in self._spans if s.name.startswith(prefix)]
+
+    def totals(self) -> dict[str, tuple[int, float]]:
+        """name -> (count, seconds) over every span recorded."""
+        with self._lock:
+            return {k: (v[0], v[1]) for k, v in self._totals.items()}
+
+    def program_totals(self, name: str) -> dict[str, dict]:
+        """program -> {count, first_s, total_s} of the ``name`` spans that
+        carry a ``program`` argument."""
+        with self._lock:
+            return {prog: {"count": v[0], "first_s": v[1], "total_s": v[2]}
+                    for (n, prog), v in self._programs.items() if n == name}
+
+    @property
+    def events(self) -> list[dict]:
+        """The kept spans as Chrome-trace events (microseconds since the
+        Unix epoch; a count is an instant event)."""
+        pid = os.getpid()
+        out = []
+        for s in self.spans():
+            ev = {"name": s.name, "cat": s.name.split(".")[0],
+                  "ts": s.start_ns / 1e3, "pid": pid, "tid": s.tid}
+            if s.end_ns > s.start_ns:
+                ev.update(ph="X", dur=(s.end_ns - s.start_ns) / 1e3)
+            else:
+                ev.update(ph="i", s="t")
+            if s.args:
+                ev["args"] = s.args
+            out.append(ev)
+        return out
 
     def chrome_trace(self) -> dict:
         """The ``chrome://tracing`` / Perfetto JSON document."""
@@ -265,6 +362,84 @@ class SpanRecorder:
         with open(path, "w") as f:
             json.dump(self.chrome_trace(), f, indent=1)
         return path
+
+
+# JAX's compile pipeline, as ``jax.monitoring`` reports it: time spans
+# (``time.time()`` seconds, the recorder's clock) named by program, and
+# the persistent compilation cache's hits and writes.
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+NESTING = (TRACE_EVENT, LOWER_EVENT)
+JAX_SPANS = {
+    TRACE_EVENT: "jax.trace",
+    LOWER_EVENT: "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+JAX_COUNTS = {
+    "/jax/compilation_cache/cache_hits": "jax.cache_hit",
+    "/jax/compilation_cache/cache_misses": "jax.cache_miss",
+}
+
+_process_lock = threading.Lock()
+_process: Optional[SpanRecorder] = None
+
+
+def process_recorder(create: bool = True) -> Optional[SpanRecorder]:
+    """The process-wide ``SpanRecorder``: the default recorder of
+    ``build_simulation`` and ``run_fleet``.
+
+    The first call makes it and registers ``jax.monitoring`` listeners
+    that record every later trace, lowering and backend compile (or
+    compile-cache fetch) as ``jax.trace`` / ``jax.lower`` /
+    ``jax.compile`` spans with the program's name, and every persistent
+    cache hit and write as a ``jax.cache_hit`` / ``jax.cache_miss``
+    count.  Importing this module registers nothing.  ``create=False``
+    returns None until some caller has made it.  A trace nested in
+    another trace or in a lowering is part of it and is not recorded on
+    its own.
+    """
+    global _process
+    with _process_lock:
+        if _process is None and create:
+            rec = SpanRecorder()
+            # JAX reports each span's start as a scalar when it opens; a
+            # trace opened inside another trace or a lowering (every jitted
+            # helper a program calls, every lowering rule that traces) is
+            # part of the outer span and is not kept
+            depth = threading.local()
+
+            def on_open(event, value, **kw):
+                if event in NESTING:
+                    depth.n = getattr(depth, "n", 0) + 1
+
+            def on_span(event, start, end, **kw):
+                name = JAX_SPANS.get(event)
+                if name is None:
+                    return
+                if event in NESTING:
+                    depth.n = max(getattr(depth, "n", 1) - 1, 0)
+                    if event == TRACE_EVENT and depth.n:
+                        return
+                rec.record(name, int(start * 1e9), int(end * 1e9),
+                           program=str(kw.get("fun_name", "")))
+
+            def on_event(event, **kw):
+                name = JAX_COUNTS.get(event)
+                if name is not None:
+                    rec.count(name)
+
+            jax.monitoring.register_scalar_listener(on_open)
+            jax.monitoring.register_event_time_span_listener(on_span)
+            jax.monitoring.register_event_listener(on_event)
+            _process = rec
+        return _process
+
+
+def compile_report() -> dict:
+    """program -> {count, first_s, total_s} of the backend compiles (or
+    cache fetches) the process recorder holds: a program compiled more
+    than once shows a count above 1."""
+    return process_recorder().program_totals("jax.compile")
 
 
 # ---------------------------------------------------------------------------

@@ -6,6 +6,7 @@ stability) live in ``test_metrics_contract.py``."""
 import csv
 import json
 import os
+import time
 
 import numpy as np
 import pytest
@@ -159,6 +160,109 @@ def test_span_recorder_chrome_trace(tmp_path):
     rec.write(path)
     with open(path) as fh:
         assert json.load(fh)["traceEvents"]
+
+
+def test_span_recorder_stamps_the_wall_clock():
+    rec = TEL.SpanRecorder()
+    before = time.time_ns()
+    with rec.span("outer"):
+        rec.count("hit")
+    after = time.time_ns()
+    outer, = rec.spans("outer")
+    hit, = rec.spans("hit")
+    assert before <= outer.start_ns <= hit.start_ns == hit.end_ns \
+        <= outer.end_ns <= after
+    assert rec.totals()["hit"] == (1, 0.0)
+    instant = next(e for e in rec.events if e["name"] == "hit")
+    assert instant["ph"] == "i" and instant["ts"] == hit.start_ns / 1e3
+
+
+def test_span_recorder_buffer_stays_bounded():
+    rec = TEL.SpanRecorder()
+    n = TEL.SpanRecorder.CAPACITY + 100
+    for i in range(n):
+        rec.record("x", i, i + 10, program=f"p{i % 3}")
+    kept = rec.spans()
+    assert len(kept) == TEL.SpanRecorder.CAPACITY
+    assert kept[0].start_ns == 100 and kept[-1].start_ns == n - 1
+    # totals still cover every span recorded
+    assert rec.totals()["x"] == pytest.approx((n, n * 10e-9))
+    per = rec.program_totals("x")
+    assert sum(v["count"] for v in per.values()) == n
+    assert per["p0"]["first_s"] == pytest.approx(10e-9)
+
+
+def test_compile_report_counts_a_recompile():
+    rec = TEL.process_recorder()
+    assert TEL.process_recorder() is rec          # one per process
+
+    @jax.jit
+    def recompiled_in_this_test(x):
+        return x * 3.0
+
+    recompiled_in_this_test(jnp.ones(3))
+    recompiled_in_this_test(jnp.ones(3))          # cached: no compile
+    recompiled_in_this_test(jnp.ones(5))          # a new shape compiles
+    got = TEL.compile_report()["jit(recompiled_in_this_test)"]
+    assert got["count"] == 2
+    assert 0.0 < got["first_s"] < got["total_s"]
+
+
+def test_process_recorder_holds_build_and_jax_spans_on_one_clock():
+    from repro.fleet import FleetConfig, FleetTopology, build_simulation
+    rec = TEL.process_recorder()
+    before = time.time_ns()
+    sim = build_simulation(FleetConfig(
+        topology=FleetTopology(num_cells=2, clients_per_cell=4), rounds=1,
+        seed=9173))
+    sim.simulate(sim.params, sim.round_keys)
+    after = time.time_ns()
+    build = [s for s in rec.spans("fleet.build") if s.start_ns >= before]
+    top = [s for s in build if s.name == "fleet.build"]
+    assert len(top) == 1
+    b = top[0]
+    assert b.args == {"mode": "sync", "clients": 8}
+    assert {s.name for s in build} == {
+        "fleet.build", "fleet.build.population", "fleet.build.task",
+        "fleet.build.init", "fleet.build.round"}
+    assert all(b.start_ns <= s.start_ns <= s.end_ns <= b.end_ns
+               for s in build)
+    jax_spans = [s for s in rec.spans("jax.") if s.start_ns >= before
+                 and s.end_ns > s.start_ns]
+    assert {s.name for s in jax_spans} >= {"jax.trace", "jax.lower",
+                                           "jax.compile"}
+    assert all(s.end_ns <= after for s in jax_spans)
+    assert all(s.args["program"] for s in jax_spans)
+    # the round compiles on the first call, after the build
+    rnd = [s for s in jax_spans if s.args["program"] == "jit(simulate)"
+           and s.name == "jax.compile"]
+    assert rnd and rnd[-1].start_ns >= b.end_ns
+    # no trace is kept inside another trace
+    traces = sorted((s.start_ns, s.end_ns) for s in jax_spans
+                    if s.name == "jax.trace")
+    assert all(b0 <= a1 for (a0, b0), (a1, b1) in zip(traces, traces[1:]))
+
+
+def test_profiler_host_events_share_the_recorder_clock(tmp_path):
+    from jax.profiler import ProfileData
+    rec = TEL.SpanRecorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("telemetry.clock_probe"):
+            time.sleep(0.02)
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+    pd = ProfileData.from_file(str(path))
+    # host events are stored relative to the capture's start time
+    start = next(dict(p.stats)["profile_start_time"] for p in pd.planes
+                 if p.name == "Task Environment")
+    ev = next(e for p in pd.planes if p.name.startswith("/host:")
+              for line in p.lines for e in line.events
+              if e.name == "telemetry.clock_probe")
+    ours, = rec.spans("telemetry.clock_probe")
+    assert abs(start + ev.start_ns - ours.start_ns) < 5e6        # 5 ms
+    assert abs(ev.duration_ns - (ours.end_ns - ours.start_ns)) < 5e6
 
 
 # ---------------------------------------------------------------------------
