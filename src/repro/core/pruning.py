@@ -30,6 +30,9 @@ import numpy as np
 __all__ = [
     "prunable",
     "magnitude_masks",
+    "magnitude_thresholds",
+    "threshold_masks",
+    "uses_selection",
     "block_masks",
     "apply_masks",
     "achieved_rate",
@@ -103,24 +106,154 @@ def ones_masks(params: PyTree) -> PyTree:
     return jax.tree.map(lambda w: jnp.ones(w.shape, dtype=bool), params)
 
 
-def magnitude_masks(params: PyTree, prune_rate: float) -> PyTree:
-    """Global unstructured magnitude pruning at rate ``prune_rate``.
+# Below this many prunable elements the magnitude threshold comes from one
+# sort of |w|; from it on, from the bit-pattern selection of
+# ``magnitude_thresholds``.  The sort is a compare-exchange network of
+# ~log2(n)^2 / 2 passes over the n values; the selection is one pass per
+# bit of the dtype, each one compare per value and rate.  On a TPU v5e
+# (host clock, median of 7 calls, bf16): 134.5M weights and 16 rates, sort
+# 602 ms against selection 33.7 ms (8.0 ms for one rate); with passes that
+# compared int32 bit patterns (44 ms at that size), 2^20 weights took
+# 2.20 ms sorted against 0.67 (one rate) and 0.95 (16), from 2^16 down both
+# took the ~0.6 ms of a call, and 600 float32 weights with 4075 rates (the
+# 1M fleet's chunk) read 0.66 ms sorted, 0.69 selected.  The cut keeps every
+# small model's program as it was.  The rate count needs no rule of its
+# own: 16 compares per weight and rate are a few passes over the (clients,
+# weights) gradient batch that the caller's step writes anyway.
+SELECT_MIN_ELEMENTS = 1 << 20
 
-    The threshold is computed over *all* prunable leaves jointly, matching
-    rho = pruned-bytes / model-bytes as in the paper.
+
+def _prunable_mags(params: PyTree) -> list:
+    leaves, _, flags = _flatten_prunable(params)
+    return [l for l, f in zip(leaves, flags) if f]
+
+
+def uses_selection(params: PyTree) -> bool:
+    """Whether ``magnitude_masks`` finds its threshold by selection: a rule
+    on the number of prunable elements (large, and small enough that the
+    int32 counts of the selection cannot overflow)."""
+    n = sum(m.size for m in _prunable_mags(params))
+    return SELECT_MIN_ELEMENTS <= n < 2 ** 31
+
+
+def _count(mats: list, pred, vals: jnp.ndarray) -> jnp.ndarray:
+    """int32 count, for each of ``vals``, of the |w| with ``pred(|w|,
+    value)``: summed leaf by leaf, the compare fused into each leaf's
+    reduction."""
+    total = jnp.zeros(vals.shape, jnp.int32)
+    for m in mats:
+        v = vals.reshape(vals.shape + (1,) * m.ndim)
+        total = total + jnp.sum(pred(jnp.abs(m), v), axis=tuple(
+            range(vals.ndim, vals.ndim + m.ndim)), dtype=jnp.int32)
+    return total
+
+
+def _min_above(mats: list, vals: jnp.ndarray) -> jnp.ndarray:
+    """For each of ``vals``, the smallest |w| above it (+inf where none)."""
+    out = None
+    for m in mats:
+        a = jnp.abs(m)
+        v = vals.reshape(vals.shape + (1,) * m.ndim)
+        least = jnp.min(jnp.where(a > v, a, jnp.inf), axis=tuple(
+            range(vals.ndim, vals.ndim + m.ndim)))
+        out = least if out is None else jnp.minimum(out, least)
+    return out
+
+
+def magnitude_thresholds(params: PyTree, rates) -> jnp.ndarray:
+    """Global magnitude threshold for each pruning rate in ``rates``.
+
+    ``jnp.quantile(|w|, clip(rate, 0, 1))`` of the concatenated |w| of
+    every prunable leaf (the default "linear" method, op for op as JAX 0.9
+    computes it), without sorting or concatenating the weights.  The two
+    order statistics the interpolation reads are found exactly, by
+    bisection on the bit patterns of |w| (for a non-negative float the
+    pattern orders as the value does): one pass of "count |w| below a
+    candidate" per bit, for all rates at once, and one more pass for the
+    next value above.  Where a rate falls between two order statistics, a
+    backend that contracts the interpolation into a fused multiply-add may
+    round it one float32 ulp apart from the quantile's program, as XLA:CPU
+    does from one shape of program to the next; the rounding to the weights'
+    dtype, and the masks, almost never see it.
     """
-    prune_rate = jnp.clip(prune_rate, 0.0, 1.0)
+    mats = _prunable_mags(params)
+    dtype = jnp.result_type(*mats)
+    q = jnp.clip(jnp.asarray(rates), 0.0, 1.0)
+    shape, q = q.shape, q.reshape(-1)
+    n = sum(m.size for m in mats)
+
+    # the positions and weights of jnp.quantile's "linear" rule, op for op
+    # in q's dtype (at 134.5M weights n - 1 itself rounds in float32)
+    nq = jax.lax.convert_element_type(n, q.dtype)
+    pos = jax.lax.mul(q, nq - 1)
+    low, high = jax.lax.floor(pos), jax.lax.ceil(pos)
+    high_w = jax.lax.sub(pos, low)
+    low_w = jax.lax.sub(jnp.ones((), high_w.dtype), high_w)
+    low = jax.lax.clamp(jnp.zeros((), low.dtype), low, nq - 1)
+    high = jax.lax.clamp(jnp.zeros((), high.dtype), high, nq - 1)
+    # the quantile's gather clamps an index past the end to the last value
+    k_low = jnp.clip(jax.lax.convert_element_type(low, int), 0, n - 1)
+    k_high = jnp.clip(jax.lax.convert_element_type(high, int), 0, n - 1)
+
+    nbits = jnp.dtype(dtype).itemsize * 8
+    itype = {16: jnp.int16, 32: jnp.int32, 64: jnp.int64}[nbits]
+    wide = jnp.int64 if nbits == 64 else jnp.int32
+    value = lambda x: jax.lax.bitcast_convert_type(x.astype(itype), dtype)
+    inf = jax.lax.bitcast_convert_type(
+        jnp.asarray(jnp.inf, dtype), itype).astype(wide)
+
+    # the largest pattern x with count(|w| < x) <= k is the k-th smallest;
+    # candidates above +inf's pattern (NaNs) compare as +inf, so an
+    # infinite k-th value lands on +inf's pattern once clipped there
+    def step(i, x):
+        cand = x | jnp.left_shift(jnp.ones((), wide), nbits - 2 - i)
+        below = _count(mats, jnp.less, value(jnp.minimum(cand, inf)))
+        return jnp.where(below <= k_low, cand, x)
+
+    v_low = value(jnp.minimum(jax.lax.fori_loop(
+        0, nbits - 1, step, jnp.zeros(q.shape, wide)), inf))
+    # the k_high-th (k_low or k_low + 1) is v_low again while the count up
+    # to v_low passes it, else the smallest |w| above v_low
+    at_most = _count(mats, jnp.less_equal, v_low)
+    v_high = jnp.where(at_most > k_high, v_low, _min_above(mats, v_low))
+
+    # any NaN in |w| makes every order statistic NaN, as the quantile has it
+    nan = functools.reduce(jnp.logical_or,
+                           [jnp.any(jnp.isnan(m)) for m in mats])
+    stat = lambda v: jnp.where(nan, jnp.nan, v.astype(q.dtype))
+    out = jax.lax.add(jax.lax.mul(stat(v_low), low_w),
+                      jax.lax.mul(stat(v_high), high_w))
+    return jax.lax.convert_element_type(out, dtype).reshape(shape)
+
+
+def threshold_masks(params: PyTree, thresh) -> PyTree:
+    """Keep the prunable weights with |w| > ``thresh`` (one scalar over all
+    prunable leaves); unprunable leaves are kept whole."""
     leaves, treedef, flags = _flatten_prunable(params)
-    mags = jnp.concatenate([jnp.abs(l).reshape(-1)
-                            for l, f in zip(leaves, flags) if f])
-    # threshold = rho-quantile of |w|; keep w where |w| > threshold
-    thresh = jnp.quantile(mags, prune_rate)
     masked = [
         (jnp.abs(l) > thresh) if f
         else jnp.ones(l.shape, bool)
         for l, f in zip(leaves, flags)
     ]
     return jax.tree_util.tree_unflatten(treedef, masked)
+
+
+def magnitude_masks(params: PyTree, prune_rate: float) -> PyTree:
+    """Global unstructured magnitude pruning at rate ``prune_rate``.
+
+    The threshold is computed over *all* prunable leaves jointly, matching
+    rho = pruned-bytes / model-bytes as in the paper: the rho-quantile of
+    |w|, from one sort, or for a large model (``uses_selection``) from
+    ``magnitude_thresholds``' selection.
+    """
+    if uses_selection(params):
+        return threshold_masks(params,
+                               magnitude_thresholds(params, prune_rate))
+    prune_rate = jnp.clip(prune_rate, 0.0, 1.0)
+    mags = jnp.concatenate([jnp.abs(l).reshape(-1)
+                            for l in _prunable_mags(params)])
+    # threshold = rho-quantile of |w|; keep w where |w| > threshold
+    return threshold_masks(params, jnp.quantile(mags, prune_rate))
 
 
 def _pad_to_blocks(w: jnp.ndarray, block: BlockLike) -> jnp.ndarray:

@@ -336,13 +336,15 @@ def _make_batch_fn(task: TASK.FleetTask, state: PyTree, cfg: FleetConfig,
 
 
 def _client_grad(task: TASK.FleetTask, params: PyTree, rho_i: jnp.ndarray,
-                 batch: PyTree, cfg: FleetConfig, mask_kind: str = None
-                 ) -> tuple[jnp.ndarray, PyTree]:
+                 batch: PyTree, cfg: FleetConfig, mask_kind: str = None,
+                 thresh: jnp.ndarray = None) -> tuple[jnp.ndarray, PyTree]:
     """Masked local gradient: rho-level masks, grad at the pruned point,
     gradient re-masked (exactly the 5-client path's client_grad).  The
     mask rule follows ``mask_kind`` (default ``cfg.mask_kind``):
     unstructured magnitude pruning (paper-style) or block-norm threshold
-    masks on the task's tile grid (the fused kernel's).
+    masks on the task's tile grid (the fused kernel's).  ``thresh``, where
+    given, is rho_i's magnitude threshold computed beforehand
+    (``pruning.magnitude_thresholds``), and the masks only compare with it.
 
     Callers ``vmap`` it over clients, and JAX names the first scope under
     a ``vmap`` ``vmap(<scope>)`` in the ``op_name`` path: ``fleet.client``
@@ -350,7 +352,9 @@ def _client_grad(task: TASK.FleetTask, params: PyTree, rho_i: jnp.ndarray,
     own names there."""
     with jax.named_scope("fleet.client"):
         with jax.named_scope("fleet.mask"):
-            if (mask_kind or cfg.mask_kind) == "block":
+            if thresh is not None:
+                masks = pruning.threshold_masks(params, thresh)
+            elif (mask_kind or cfg.mask_kind) == "block":
                 masks = pruning.block_masks(params, rho_i,
                                             block=task.tile_grid(params))
             else:
@@ -474,11 +478,26 @@ def _fleet_grads(task: TASK.FleetTask, params: PyTree, rho: jnp.ndarray,
             return batch_fn(c_idx.reshape(-1))
 
     if cfg.kernel == "reference":
+        # a large model's magnitude thresholds come from one selection for
+        # the whole chunk, outside the client vmap (the weights are shared)
+        select = cfg.mask_kind == "magnitude" and \
+            pruning.uses_selection(params)
+
         def step(c_idx, c_rho, c_w, c_lw, *extra):
             batch = _constrain_clients(batches(c_idx, extra), mesh)
-            losses, grads = jax.vmap(
-                lambda b, ri: _client_grad(task, params, ri, b, cfg)
-            )(batch, c_rho.reshape(-1))
+            if select:
+                with jax.named_scope("fleet.mask"), \
+                        jax.named_scope("fleet.mask.select"):
+                    thresh = pruning.magnitude_thresholds(
+                        params, c_rho.reshape(-1))
+                losses, grads = jax.vmap(
+                    lambda b, t: _client_grad(task, params, None, b, cfg,
+                                              thresh=t)
+                )(batch, thresh)
+            else:
+                losses, grads = jax.vmap(
+                    lambda b, ri: _client_grad(task, params, ri, b, cfg)
+                )(batch, c_rho.reshape(-1))
             w_flat = c_w.reshape(-1)
             lw_flat = c_lw.reshape(-1)
             g = jax.tree.map(

@@ -196,3 +196,136 @@ def test_block_norm_state_skips_unprunable_leaves():
     assert len(state) == len(leaves)
     for st, f in zip(state, flags):
         assert (st is None) == (not f)
+
+
+# ---------------------------------------------------------------------------
+# magnitude thresholds by bit-pattern selection == the jnp.quantile rule
+# ---------------------------------------------------------------------------
+
+from fractions import Fraction  # noqa: E402
+
+SIZES = {"below": (41, 37), "above": (1024, 1024)}   # + a (3, 7, 11) leaf
+
+
+def _select_params(kind, size, dtype):
+    rng = np.random.default_rng(
+        ["normal", "ties", "zeros", "signed_zeros", "inf", "nan"].index(kind))
+    a = rng.normal(size=SIZES[size]).astype(np.float32)
+    b = rng.normal(size=(3, 7, 11)).astype(np.float32)
+    if kind == "ties":          # a handful of distinct magnitudes
+        a, b = np.round(a * 2) / 2, np.round(b * 2) / 2
+    elif kind == "zeros":
+        a, b = 0.0 * a, 0.0 * b
+    elif kind == "signed_zeros":  # +0 and -0 half and half, a few values
+        a = np.where(rng.random(a.shape) < 0.5, 0.0, -0.0) \
+            * np.ones_like(a)
+        a.flat[:5] = [1.0, -2.0, 0.5, -0.5, 3.0]
+    elif kind == "inf":
+        a.flat[:3] = [np.inf, -np.inf, np.inf]
+    elif kind == "nan":
+        b.flat[4] = np.nan
+    return {"a": jnp.asarray(a, dtype), "b": jnp.asarray(b, dtype),
+            "bias": jnp.ones((7,), dtype)}
+
+
+def _select_rates(n, seed=0):
+    """0, 1, out-of-range rates, rates whose q (n - 1) is an exact float32
+    integer, and random rates."""
+    nm1 = np.float32(np.float32(n) - np.float32(1))
+    exact = [q for k in (1, n // 3, n // 2, n - 2)
+             for q in [np.float32(k / (n - 1))]
+             if np.floor(q * nm1) == q * nm1]
+    rand = np.random.default_rng(seed).random(4)
+    return np.asarray([0.0, 1.0, -0.25, 1.5, *exact, *rand], np.float32)
+
+
+def _mags(p):
+    return jnp.concatenate([jnp.abs(l).reshape(-1)
+                            for l in jax.tree.leaves(p) if l.ndim >= 2])
+
+
+def _round32(x: Fraction) -> np.float32:
+    """x rounded once to float32, to nearest, ties to even."""
+    f = np.float32(float(x))
+    near = [np.nextafter(f, np.float32(-np.inf)), f,
+            np.nextafter(f, np.float32(np.inf))]
+    return min(near, key=lambda c: (abs(Fraction(float(c)) - x),
+                                    int(c.view(np.uint32)) & 1))
+
+
+def _rule_roundings(mags: np.ndarray, q: np.float32) -> set:
+    """The float32 values jnp.quantile's linear rule may give at rate q:
+    products rounded on their own, or either one contracted into the sum
+    by a fused multiply-add (the backend's choice)."""
+    n = mags.size
+    nq = np.float32(n)
+    pos = np.float32(q * np.float32(nq - np.float32(1)))
+    lo, hi = np.floor(pos), np.ceil(pos)
+    hw = np.float32(pos - lo)
+    lw = np.float32(np.float32(1) - hw)
+    lv = mags[min(int(min(lo, nq - 1)), n - 1)]
+    hv = mags[min(int(min(hi, nq - 1)), n - 1)]
+    with np.errstate(invalid="ignore"):
+        p_lo, p_hi = np.float32(lv * lw), np.float32(hv * hw)
+        out = {float(np.float32(p_lo + p_hi))}
+    if np.isfinite(lv) and np.isfinite(hv):
+        out.add(float(_round32(Fraction(float(lv)) * Fraction(float(lw))
+                               + Fraction(float(p_hi)))))
+        out.add(float(_round32(Fraction(float(hv)) * Fraction(float(hw))
+                               + Fraction(float(p_lo)))))
+    return out
+
+
+@pytest.mark.parametrize("size", sorted(SIZES))
+@pytest.mark.parametrize("path", ["plain", "vmap"])
+@pytest.mark.parametrize("kind", ["normal", "ties", "zeros", "signed_zeros",
+                                  "inf", "nan"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_magnitude_selection_equals_quantile_rule(dtype, kind, path, size):
+    p = _select_params(kind, size, jnp.dtype(dtype))
+    n = int(_mags(p).size)
+    assert pruning.uses_selection(p) == (size == "above")
+    rates = _select_rates(n)
+
+    def quantile(p, r):
+        return jnp.quantile(_mags(p), jnp.clip(r, 0.0, 1.0))
+
+    def parent_masks(p, r):
+        return pruning.threshold_masks(p, quantile(p, r))
+
+    if path == "vmap":
+        got = jax.jit(jax.vmap(pruning.magnitude_thresholds, (None, 0)))(
+            p, rates)
+        want = jax.jit(jax.vmap(quantile, (None, 0)))(p, rates)
+        masks = jax.jit(jax.vmap(pruning.magnitude_masks, (None, 0)))(
+            p, rates)
+        parent = jax.jit(jax.vmap(parent_masks, (None, 0)))(p, rates)
+    else:
+        got = jax.jit(pruning.magnitude_thresholds)(p, rates)
+        want = jax.jit(quantile)(p, rates)
+        # one rate a call, against the quantile's masks at its threshold
+        one, ref = (jax.jit(pruning.magnitude_masks),
+                    jax.jit(pruning.threshold_masks))
+        stack = lambda ms: jax.tree.map(lambda *l: jnp.stack(l), *ms)
+        masks = stack([one(p, r) for r in rates])
+        parent = stack([ref(p, t) for t in want])
+    assert got.dtype == want.dtype == jnp.dtype(dtype)
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+
+    sorted_mags = np.sort(np.asarray(_mags(p), np.float32))
+    if np.isnan(sorted_mags).any():
+        assert np.isnan(got).all() and np.isnan(want).all()
+    for q, g, w in zip(rates, got, want):
+        qc = np.float32(np.clip(q, 0.0, 1.0))
+        pos = np.float32(qc * np.float32(np.float32(n) - np.float32(1)))
+        if dtype == "bfloat16" or np.floor(pos) == pos or np.isnan(w):
+            # nothing for a contraction to round: equal bit for bit
+            assert g.tobytes() == w.tobytes() or (np.isnan(g) and np.isnan(w)
+                                                  ), (q, g, w)
+        else:
+            allowed = _rule_roundings(sorted_mags, qc)
+            assert float(w) in allowed, (q, w, allowed)
+            assert float(g) in allowed, (q, g, allowed)
+    for a, b in zip(jax.tree.leaves(masks), jax.tree.leaves(parent)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

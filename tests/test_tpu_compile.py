@@ -1,4 +1,5 @@
-"""Compile the main path's Pallas kernels for a described TPU v5e.
+"""Compile the main path's Pallas kernels, and the magnitude threshold
+selection, for a described TPU v5e.
 
 Nothing runs: each test lowers a kernel at real widths for one chip of a
 ``v5e:2x2`` topology described by the installed TPU compiler and compiles
@@ -19,6 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import pruning
 from repro.kernels import fleet_fused as FF
 from repro.kernels import ops
 from repro.models import mlp
@@ -105,3 +107,21 @@ def test_fused_fleet_grads_compiles(one_chip):
                            interpret=False)
     _compile(fn, one_chip, params, _f32(c, batch, 32),
              jax.ShapeDtypeStruct((c, batch), jnp.int32), keeps, _f32(c))
+
+
+def test_magnitude_selection_compiles_fused(one_chip):
+    """The threshold selection at smollm-135m's 134.5M prunable bf16
+    weights for 16 clients: no sort, and the count passes fuse their
+    compares, so no (rates, weights) array is planned (as bool alone it
+    would be 2.15 GB)."""
+    leaves = [(49152, D), (D,), (30, D, 192), (30, D, D), (30, D, D),
+              (30, D, 192), (30, D, DFF), (30, D, DFF), (30, DFF, D)]
+    params = [jax.ShapeDtypeStruct(s, jnp.bfloat16, sharding=one_chip)
+              for s in leaves]
+    rates = jax.ShapeDtypeStruct((16,), jnp.float32, sharding=one_chip)
+    assert pruning.uses_selection(params)
+    compiled = jax.jit(pruning.magnitude_thresholds).lower(
+        params, rates).compile()
+    n = sum(a.size for a in params if a.ndim >= 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * n // 4
+    assert " sort(" not in compiled.as_text()
