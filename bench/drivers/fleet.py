@@ -23,6 +23,7 @@ The window then continues from there with the same object.
 from __future__ import annotations
 
 import contextlib
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +34,9 @@ from reference import fleet as REF
 from reference import tasks as REF_TASKS
 from yardstick import flops as FLOPS
 
+# the benchmark directory this driver was loaded from: the configuration's
+# model family is found there (families.py)
+BENCH = pathlib.Path(__file__).resolve().parents[1]
 CHECKED = 3                 # rounds that the reference follows
 KEY_CALLS = 1 << 15         # round keys drawn up front (one per call)
 FAULTS = ("stale", "half_batch", "answer")
@@ -65,7 +69,7 @@ def _program_config(config: dict, spec: dict):
     task = spec["task"]
     if "model" in config:
         fleet_task = TransformerTask(
-            arch=program.arch(config), seq_len=task["seq_len"],
+            arch=program.arch(config, BENCH), seq_len=task["seq_len"],
             local_batch=task["local_batch"], pool_clients=task["pool_clients"])
     else:
         fleet_task = SyntheticMLPTask(
@@ -141,7 +145,7 @@ class Driver:
     def __init__(self, config: dict, traffic: dict, seed: int):
         self.config, self.traffic, self.seed = config, traffic, seed
         self.spec = resolve(config, traffic)
-        self.ref_task = REF_TASKS.make(config, self.spec["task"])
+        self.ref_task = REF_TASKS.make(config, self.spec["task"], BENCH)
         # set by a control or a fault run: the reference in the program's
         # place, as (precision, fault)
         self.stand_in = None
@@ -282,8 +286,8 @@ class Driver:
             m = per_cell
         clients = topo["num_cells"] * m
         if "model" in self.config:
-            per = FLOPS.train_flops(self.config["model"], task["local_batch"],
-                                    task["seq_len"])
+            per = self.ref_task.family.train_flops(
+                self.config["model"], task["local_batch"], task["seq_len"])
         else:
             dims = [task["feature_dim"], *task["hidden"], task["num_classes"]]
             per = FLOPS.mlp_train_flops(dims, task["local_batch"])

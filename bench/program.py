@@ -1,23 +1,19 @@
 """The system under test, built from a configuration file.
 
-The only module of the benchmark besides the drivers that imports the
-program.  ``arch`` maps a configuration's Hugging Face keys onto the
-program's ``ArchConfig`` (a single stage of attention + MLP blocks, as the
-llama family has it).
+The modules of the benchmark that import the program are this one, the
+drivers and the family mappings ``arch/<model_type>.py``; nothing under
+``reference/`` does.  ``arch`` finds the configuration's family mapping
+by its ``model_type`` (``families.py``).
 """
 
 from __future__ import annotations
 
+import pathlib
 
-def arch(config: dict):
-    from repro.configs import get_config
-    from repro.configs.base import BlockSpec, StageSpec
-    m = config["model"]
-    return get_config(config["program_arch"]).replace(
-        d_model=m["hidden_size"], num_heads=m["num_attention_heads"],
-        num_kv_heads=m["num_key_value_heads"], d_ff=m["intermediate_size"],
-        vocab_size=m["vocab_size"], rope_theta=float(m["rope_theta"]),
-        tie_embeddings=m["tie_word_embeddings"],
-        stages=(StageSpec(m["num_hidden_layers"],
-                          (BlockSpec("attn", "mlp"),)),),
-        param_dtype=m["torch_dtype"], compute_dtype=m["torch_dtype"])
+import families
+
+
+def arch(config: dict, bench: pathlib.Path = families.BENCH):
+    """The program's ``ArchConfig`` of ``config``, from its family's
+    ``arch/<model_type>.py`` under ``bench``."""
+    return families.load("arch", config["model"], bench).arch(config)

@@ -1,4 +1,5 @@
-"""Plain float32 reference of a llama-style causal LM (SmolLM and kin).
+"""Plain float32 reference of a llama-style causal LM (SmolLM and kin): the
+``llama`` family, and the default of a configuration with no ``model_type``.
 
 Reads the configuration's own keys (Hugging Face names).  RMSNorm, rotary
 embeddings on the two halves of each head, grouped-query causal attention,
@@ -10,6 +11,7 @@ uses (normal draws scaled by fan-in, embeddings by 0.02, norm scales of
 one), so that the reference and the system start from the same numbers
 without the reference taking any of the system's arrays.  The tree of
 ``init`` has the system's leaf names, so leaves compare by path.
+``train_flops`` is ``yardstick.flops.train_flops``, counted from shapes.
 """
 
 from __future__ import annotations
@@ -19,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from reference import precision as PR
+from yardstick import flops as FLOPS
 
 DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
 
@@ -136,3 +139,9 @@ def loss(cfg: dict, params: dict, batch: dict, precision: str = "f32"):
     logp = jax.nn.log_softmax(lg, axis=-1)
     nll = -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)[..., 0]
     return jnp.mean(nll)
+
+
+def train_flops(cfg: dict, batch: int, seq: int) -> float:
+    """Operations of one client's forward and backward over ``batch`` rows
+    of ``seq`` tokens."""
+    return FLOPS.train_flops(cfg, batch, seq)

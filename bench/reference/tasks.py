@@ -4,19 +4,22 @@ Each task draws its data and its initial weights from keys laid out as the
 system under test lays them out, and computes its loss in float32 through
 ``precision.einsum``.  Nothing here imports the system.
 
-* ``MLPTask``   -- per-class Gaussian templates, an MLP classifier (ReLU
-                   hidden layers), mean cross-entropy.
-* ``LlamaTask`` -- a fixed pool of Zipf/Markov token rows (client ``i`` owns
-                   row ``i mod pool``), the ``llama`` reference model.
+* ``MLPTask`` -- per-class Gaussian templates, an MLP classifier (ReLU
+                 hidden layers), mean cross-entropy.
+* ``LMTask``  -- a fixed pool of Zipf/Markov token rows (client ``i`` owns
+                 row ``i mod pool``), the reference model of the
+                 configuration's family (``reference/<model_type>.py``).
 """
 
 from __future__ import annotations
+
+import pathlib
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from reference import llama
+import families
 from reference import precision as PR
 
 
@@ -89,13 +92,16 @@ class TokenStream:
         return out
 
 
-class LlamaTask:
-    def __init__(self, model: dict, spec: dict):
-        self.model = model
+class LMTask:
+    """A causal language model of ``family``, the module of its plain
+    reference: ``init``, ``loss`` and ``train_flops``."""
+
+    def __init__(self, family, model: dict, spec: dict):
+        self.family, self.model = family, model
         self.seq = spec["seq_len"]
         self.batch = spec["local_batch"]
         self.pool_rows = spec["pool_clients"]
-        self.param_dtype = llama.DTYPES[model["torch_dtype"]]
+        self.param_dtype = jnp.dtype(model["torch_dtype"])
         self.client_chunk = 1
 
     def build(self, task_key, data_key):
@@ -108,16 +114,19 @@ class LlamaTask:
                                              self.seq))
 
     def init(self, key):
-        return llama.init(self.model, key)
+        return self.family.init(self.model, key)
 
     def client_batch(self, idx):
         return {"tokens": self.pool[idx % self.pool_rows]}
 
     def loss(self, params, batch, precision="f32"):
-        return llama.loss(self.model, params, batch, precision)
+        return self.family.loss(self.model, params, batch, precision)
 
 
-def make(config: dict, spec: dict):
+def make(config: dict, spec: dict, bench: pathlib.Path = families.BENCH):
+    """The configuration's task; a model's family is found under
+    ``bench``."""
     if "model" in config:
-        return LlamaTask(config["model"], spec)
+        model = config["model"]
+        return LMTask(families.load("reference", model, bench), model, spec)
     return MLPTask(config["task"])

@@ -9,8 +9,10 @@ is ``bench/configs/<config>.json``, its traffic mix
 the limits of its correctness
 check ``bench/limits/<cell>.json``, and each per-layer metric a reader
 description ``bench/metrics/<metric>.json`` or a reader of its own,
-``bench/metrics/<metric>.py``.  Adding any of them takes new
-files only.
+``bench/metrics/<metric>.py``.  A model family (the configuration's
+``model_type``) is its program mapping ``bench/arch/<model_type>.py`` and
+its plain reference ``bench/reference/<model_type>.py`` (``families.py``).
+Adding any of them takes new files only.
 
 One run is one process:
 
@@ -36,7 +38,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -54,6 +55,8 @@ SETTLE_SECONDS = 1.0
 TRACE_DIR = ROOT / ".bench_trace"
 CACHE_DIR = ROOT / ".jax_cache"
 
+from families import load_module  # noqa: E402
+
 
 class NoChip(RuntimeError):
     pass
@@ -62,15 +65,6 @@ class NoChip(RuntimeError):
 def load_json(path: pathlib.Path) -> dict:
     with open(path) as f:
         return json.load(f)
-
-
-def load_module(path: pathlib.Path):
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{path.parent.name}_{path.stem}".replace(".", "_").replace(
-            "-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 class Cell:
@@ -126,6 +120,10 @@ def enable_compile_cache() -> None:
         jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # the key then holds each op's scope name (op_name): a program cached
+    # before a scope was renamed is compiled again, not served with the
+    # old names, which the per-layer scope metrics would not find
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def window(driver, seconds: float, annotate) -> tuple[int, float, float]:

@@ -148,3 +148,18 @@ def test_refuses_without_tpu():
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
     assert "no TPU" in proc.stderr
+
+
+def test_compile_cache_key_holds_scope_names():
+    import jax
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes",
+             "jax_compilation_cache_include_metadata_in_key")
+    saved = {n: getattr(jax.config, n) for n in names}
+    try:
+        run.enable_compile_cache()
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+    finally:
+        for n, v in saved.items():
+            jax.config.update(n, v)
