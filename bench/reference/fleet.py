@@ -67,6 +67,31 @@ def round_key(deployment_seed: int, k_order, call):
     return pool[order[pos]]
 
 
+def thresholds(params, rho):
+    """Global magnitude threshold of each client's rate: the linear
+    interpolation of the sorted |w| of every matrix, at the float32
+    position rho (n - 1), in the matrices' common dtype (``result_type``,
+    the dtype of their concatenation, so a float32 matrix among bfloat16
+    ones makes it float32)."""
+    mats = [l for l in jax.tree_util.tree_leaves(params) if l.ndim >= 2]
+    dt = jnp.result_type(*mats)
+    mags = jnp.sort(jnp.concatenate([jnp.abs(l).reshape(-1) for l in mats]))
+    n = jnp.float32(mags.shape[0])
+    pos = jnp.clip(rho, 0.0, 1.0).astype(jnp.float32) * (n - 1.0)
+    lo, hi = jnp.floor(pos), jnp.ceil(pos)
+    w_hi = pos - lo
+    v_lo = mags[lo.astype(jnp.int32)]
+    v_hi = mags[hi.astype(jnp.int32)]
+    return v_lo * (1.0 - w_hi).astype(dt) + v_hi * w_hi.astype(dt)
+
+
+def masks(params, thresh):
+    """Keep each matrix's weights with |w| > ``thresh``; every other leaf
+    whole."""
+    return jax.tree.map(lambda l: (jnp.abs(l) > thresh) if l.ndim >= 2
+                        else jnp.ones(l.shape, bool), params)
+
+
 class Fleet:
     """The deployment: population, wireless constants and the schedule."""
 
@@ -259,32 +284,14 @@ class Fleet:
 
     # -- learning -----------------------------------------------------------
 
-    def thresholds(self, params, rho):
-        """Global magnitude threshold of each client's rate: the linear
-        interpolation of the sorted |w| of every matrix, at the float32
-        position rho (n - 1), in the parameters' dtype."""
-        mats = [l for l in jax.tree_util.tree_leaves(params) if l.ndim >= 2]
-        dt = mats[0].dtype
-        mags = jnp.sort(jnp.concatenate([jnp.abs(l).reshape(-1)
-                                         for l in mats]))
-        n = jnp.float32(mags.shape[0])
-        pos = jnp.clip(rho, 0.0, 1.0).astype(jnp.float32) * (n - 1.0)
-        lo, hi = jnp.floor(pos), jnp.ceil(pos)
-        w_hi = pos - lo
-        v_lo = mags[lo.astype(jnp.int32)]
-        v_hi = mags[hi.astype(jnp.int32)]
-        return v_lo * (1.0 - w_hi).astype(dt) + v_hi * w_hi.astype(dt)
-
     def _client(self, params, thresh, idx, precision):
-        masks = jax.tree.map(
-            lambda l: (jnp.abs(l) > thresh) if l.ndim >= 2
-            else jnp.ones(l.shape, bool), params)
+        keep = masks(params, thresh)
         pruned = jax.tree.map(lambda l, m: jnp.where(m, l, 0).astype(
-            jnp.float32), params, masks)
+            jnp.float32), params, keep)
         batch = self.task.client_batch(idx)
         loss, g = jax.value_and_grad(
             lambda p: self.task.loss(p, batch, precision))(pruned)
-        return loss, jax.tree.map(lambda g, m: jnp.where(m, g, 0.0), g, masks)
+        return loss, jax.tree.map(lambda g, m: jnp.where(m, g, 0.0), g, keep)
 
     @functools.partial(jax.jit, static_argnums=(0, 3, 4))
     def update(self, params, ctl, precision="f32", fault=None):
@@ -293,7 +300,7 @@ class Fleet:
         idx = (jnp.arange(c)[:, None] * self.shape[1]
                + ctl["cohort"]).reshape(-1)
         wts = ctl["agg_w"].reshape(-1)
-        thresh = self.thresholds(params, ctl["rho_c"].reshape(-1))
+        thresh = thresholds(params, ctl["rho_c"].reshape(-1))
         n = idx.shape[0]
         if fault == "half_batch":        # the second half of the clients
             wts = wts * (jnp.arange(n) < n // 2)

@@ -1,5 +1,6 @@
-"""The harness on the CPU: cells assembled from their files by name, a new
-mix or metric added as files only, and no result without a TPU."""
+"""The harness on the CPU: cells assembled from their files by name (the
+cells of ``BENCHMARK.json`` and fixture families' cells added to a copy),
+a new mix or metric added as files only, and no result without a TPU."""
 
 import json
 import os
@@ -45,12 +46,21 @@ def test_benchmark_json_names_and_bounds():
         assert (ROOT / c["file"]).exists()
 
 
-@pytest.mark.parametrize("name", [w["name"] for w in BENCHMARK["workloads"]])
-def test_cell_assembled_by_name(name):
-    cell = run.Cell(name)
+@pytest.fixture(scope="module")
+def fixture_copy(tmp_path_factory):
+    return tiny.add_fixture_cells(tmp_path_factory.mktemp("fixture"))
+
+
+@pytest.mark.parametrize("name", [w["name"] for w in BENCHMARK["workloads"]]
+                         + list(tiny.FIXTURE_CELLS))
+def test_cell_assembled_by_name(fixture_copy, name):
+    """Each cell of ``BENCHMARK.json``, and each fixture family's cell added
+    to a copy as new files and entries, assembles from its files."""
+    bench, benchmark = tiny.cell_site(name, fixture_copy)
+    cell = run.Cell(name, bench, benchmark)
     config, mix = name.split(".")
     assert cell.config["name"] == config
-    assert (BENCH / "traffic" / f"{mix}.json").exists()
+    assert (bench / "traffic" / f"{mix}.json").exists()
     assert cell.driver_module.Driver.__name__ == "Driver"
     assert "setup_s" in [m["name"] for m in cell.end_to_end]
     assert len(cell.end_to_end) >= 2 and cell.per_layer

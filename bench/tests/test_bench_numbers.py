@@ -95,3 +95,35 @@ def test_payload_is_the_models_own_bits():
     fleet = REF.Fleet(spec, task)
     fleet.set_model_bits(jax.eval_shape(task.init, jax.random.PRNGKey(0)))
     assert fleet.model_bits == 610 * 32
+
+
+def test_thresholds_follow_the_program_on_mixed_dtypes():
+    """bfloat16 matrices beside a float32 router, as an expert model draws
+    them: the reference's thresholds are ``jnp.quantile`` of the
+    concatenated |w| (float32) to one float32 ulp, and its masks are the
+    program's (``magnitude_thresholds``, ``threshold_masks``) leaf for
+    leaf."""
+    import jax
+    import jax.numpy as jnp
+    from reference import fleet as REF
+    from repro.core import pruning
+    k = jax.random.split(jax.random.PRNGKey(3), 4)
+    params = {
+        "attn": {"w": jax.random.normal(k[0], (32, 48), jnp.bfloat16)},
+        "ffn": {"w": jax.random.normal(k[1], (48, 32), jnp.bfloat16)},
+        "norm": {"scale": jnp.ones((32,), jnp.bfloat16)},
+        "router": {"w": jax.random.normal(k[2], (32, 8)) * 0.02}}
+    rates = jnp.asarray([0.0, 0.1, 0.3337, 0.5, 0.7, 0.9001, 1.0])
+    ref = np.asarray(REF.thresholds(params, rates))
+    assert ref.dtype == np.float32
+    mags = jnp.concatenate([jnp.abs(params[n]["w"]).reshape(-1)
+                            for n in ("attn", "ffn", "router")])
+    quantile = np.asarray(jnp.quantile(mags, rates))
+    assert quantile.dtype == np.float32
+    assert np.all(np.abs(ref - quantile) <= np.spacing(quantile))
+    prog = pruning.magnitude_thresholds(params, rates)
+    for r, p in zip(ref, prog):
+        mine = REF.masks(params, r)
+        theirs = pruning.threshold_masks(params, p)
+        for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(theirs)):
+            np.testing.assert_array_equal(a, b)

@@ -4,13 +4,15 @@
 only, a tiny llama configuration, a tiny fleet configuration and a mix of
 its own, with limits that hold for sound runs at these sizes.  It returns
 the copied directory and a ``BENCHMARK.json``-shaped dict naming the tiny
-cells.  ``add_family(bench, benchmark)`` adds to such a copy, as new files
-only, the fixture family ``llama_untied`` (``fixtures/family/``) and a tiny
-cell of it.
+cells.  ``add_fixture_cells(tmp)`` makes such a copy and adds to it, as new
+files and new entries only, two fixture families (``fixtures/family/``)
+with a tiny cell each: ``llama_untied``, whose output head is its own
+matrix, and ``llama_recount``, whose work count is not the llama count.
 """
 
 from __future__ import annotations
 
+import copy
 import json
 import pathlib
 import shutil
@@ -91,29 +93,73 @@ FAMILY_LIMITS = {"init_gap": 0.0, "loss_gap": 0.0215, "grad_gap": 0.0717,
                  "deadline_gap": 4.53e-05, "bandwidth_gap": 1.64e-03}
 
 
-def add_family(bench: pathlib.Path, benchmark: dict) -> list[pathlib.Path]:
-    """The fixture family's two files, a tiny configuration of it and its
-    cell's limits, written into ``bench`` as new files; returns them."""
+RECOUNT = "llama_recount"
+RECOUNT_CELL = "tiny-recount.tiny-recount-train"
+FIXTURE_CELLS = (FAMILY_CELL, RECOUNT_CELL)
+
+
+def _add_cell(bench: pathlib.Path, benchmark: dict, family: str, cell: str,
+              model: dict, limits: dict, why: str,
+              mix: dict | None = None) -> list[pathlib.Path]:
+    """A fixture family's two files, a tiny configuration of it, its mix
+    where one is given and its cell's limits, written into ``bench`` as new
+    files, and the cell as new entries of ``benchmark``: its ``workloads``
+    entry and its name appended to each metric's list that holds
+    tiny-llama.tiny-train.  Returns the files."""
     added = []
     for kind in ("arch", "reference"):
-        dst = bench / kind / f"{FAMILY}.py"
+        added.append(bench / kind / f"{family}.py")
         shutil.copyfile(BENCH / "tests" / "fixtures" / "family" / kind
-                        / f"{FAMILY}.py", dst)
-        added.append(dst)
+                        / f"{family}.py", added[-1])
+    config_name, mix_name = cell.split(".")
     config = json.loads((bench / "configs" / "tiny-llama.json").read_text())
-    config.update(name="tiny-untied", model={
-        **TINY_LLAMA, "model_type": FAMILY, "tie_word_embeddings": False})
-    added.append(bench / "configs" / "tiny-untied.json")
+    config.update(name=config_name,
+                  model={**TINY_LLAMA, "model_type": family, **model})
+    added.append(bench / "configs" / f"{config_name}.json")
     _write(added[-1], config)
-    added.append(bench / "limits" / f"{FAMILY_CELL}.json")
-    _write(added[-1], FAMILY_LIMITS)
+    if mix is not None:
+        added.append(bench / "traffic" / f"{mix_name}.json")
+        _write(added[-1], mix)
+    added.append(bench / "limits" / f"{cell}.json")
+    _write(added[-1], limits)
     benchmark["workloads"].append(
-        {"name": FAMILY_CELL, "config": "tiny-untied", "traffic": "tiny-train",
-         "chips": 1, "why": "tiny CPU stand-in of a family added as files"})
+        {"name": cell, "config": config_name, "traffic": mix_name,
+         "chips": 1, "why": why})
     for m in benchmark["end_to_end"] + benchmark["per_layer"]:
         if "tiny-llama.tiny-train" in m.get("workloads", []):
-            m["workloads"].append(FAMILY_CELL)
+            m["workloads"].append(cell)
     return added
+
+
+def add_fixture_cells(tmp) -> tuple[pathlib.Path, dict, dict, list]:
+    """``make_bench(tmp)`` with both fixture families' cells added as new
+    files and entries: the copy, its ``BENCHMARK.json``-shaped dict before
+    and after the additions, and the files added."""
+    bench, benchmark = make_bench(tmp)
+    before = copy.deepcopy(benchmark)
+    added = _add_cell(bench, benchmark, FAMILY, FAMILY_CELL,
+                      {"tie_word_embeddings": False}, FAMILY_LIMITS,
+                      "tiny CPU stand-in of a family added as files")
+    # llama_recount brings a mix of its own, as a model_config change does:
+    # tiny-train's under its own name.  Its model and mix are those of
+    # tiny-llama.tiny-train, whose limits therefore hold for it.
+    read = lambda *p: json.loads(bench.joinpath(*p).read_text())
+    added += _add_cell(
+        bench, benchmark, RECOUNT, RECOUNT_CELL, {},
+        read("limits", "tiny-llama.tiny-train.json"),
+        "tiny CPU stand-in of a family that counts its own work",
+        mix=read("traffic", "tiny-train.json"))
+    return bench, before, benchmark, added
+
+
+def cell_site(name: str, fixture_copy: tuple) -> tuple[pathlib.Path, dict]:
+    """(benchmark directory, ``BENCHMARK.json``-shaped dict) that hold the
+    cell ``name``: the repository's for a cell of ``BENCHMARK.json``, the
+    copy of ``add_fixture_cells`` for a fixture cell."""
+    if name in FIXTURE_CELLS:
+        bench, _, benchmark, _ = fixture_copy
+        return bench, benchmark
+    return BENCH, json.loads((BENCH.parent / "BENCHMARK.json").read_text())
 
 
 def run_tiny(bench: tuple, name: str, stand_in=None) -> dict:

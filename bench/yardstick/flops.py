@@ -2,15 +2,19 @@
 
 These count what the mathematics of a step requires, never what a compiler
 emits: no masked zeros, no recompute, no control flow.  Multiply-adds count
-two operations.
+two operations.  A cell's work is its model family's count,
+``reference/<model_type>.py::train_flops``: ``train_flops`` here is the
+``llama`` family's, not every model's, and a family whose layers it does
+not describe (experts, latent attention, another head) counts its own.
 
 * ``mlp_train_flops``  -- forward and backward of an MLP classifier over
                           ``samples`` rows: 6 x (weights + biases) per row.
-* ``train_flops``      -- forward and backward of a llama-style causal LM on
-                          ``batch`` rows of ``seq`` tokens: 6 N per token,
-                          N the parameters that multiply (layers and the
-                          tied output embedding, not the norms), plus three
-                          times the causal attention products.
+* ``train_flops``      -- the llama family's count: forward and backward of
+                          a llama-style causal LM on ``batch`` rows of
+                          ``seq`` tokens: 6 N per token, N the parameters
+                          that multiply (layers and the tied output
+                          embedding, not the norms), plus three times the
+                          causal attention products.
 * ``attention_flops``  -- QK^T and PV of causal self-attention in the
                           forward pass: query t reads t + 1 keys.
 """
